@@ -36,8 +36,9 @@ The ``client_scaling`` scenario measures the client-sharded fused engine
 (DESIGN.md §4: ``shard_map`` over the dedicated ``client`` mesh axis,
 hierarchical two-stage AFA, per-shard power-of-two compaction) against the
 single-device one-shot fused scan at K in {10^3, 10^4, 10^5} on an 8-way
-host-device mesh (``--xla_force_host_platform_device_count=8`` — spawned as
-a subprocess when the current process has fewer devices).  Reported:
+host-device mesh (``--xla_force_host_platform_device_count=8`` — on the CPU
+spawned as a CPU-only subprocess when the current process has fewer
+devices; an accelerator host with fewer chips refuses).  Reported:
 steady-state post-blocking rounds/sec for both routes and their ratio.
 Honesty note: forced host devices SERIALIZE on the physical cores, so any
 replicated work executes once per shard with no wall-clock parallelism
@@ -321,9 +322,9 @@ def _cs_sim(K: int, **kw) -> SimConfig:
 
 def _client_scaling_core(tiny: bool) -> tuple[list[dict], list[dict]]:
     """The in-process client-scaling measurement; requires >= CS_SHARDS jax
-    devices (the public entry point ``run_client_scaling`` spawns this in a
-    subprocess with forced host devices when the current process has too
-    few)."""
+    devices (on the CPU, the public entry point ``run_client_scaling``
+    spawns this in a CPU-only subprocess with forced host devices when the
+    current process has too few)."""
     import jax
 
     assert jax.device_count() >= CS_SHARDS, jax.device_count()
@@ -409,17 +410,27 @@ _CS_MARK = "CLIENT_SCALING_JSON:"
 def run_client_scaling(tiny: bool = False) -> tuple[list[dict], list[dict]]:
     """Client-sharded engine vs single-device one-shot scan (see module
     docstring).  Runs in-process when enough devices exist (the CI
-    multi-device job sets ``--xla_force_host_platform_device_count=8``),
-    else re-execs this file as a worker subprocess with forced host
-    devices."""
+    multi-device job sets ``--xla_force_host_platform_device_count=8``).
+    On the CPU backend with fewer devices it re-execs this file as a
+    CPU-only worker subprocess with forced host devices; on an accelerator
+    it refuses, since this process already holds the chips a child would
+    need."""
     import jax
 
     if jax.device_count() >= CS_SHARDS:
         return _client_scaling_core(tiny)
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"client scaling needs {CS_SHARDS} devices in one process, but "
+            f"this {jax.default_backend()} host has {jax.device_count()}; "
+            "it runs on a host with that many chips, or on the CPU with "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={CS_SHARDS}"
+        )
     import subprocess
     import sys
 
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     flags = env.get("XLA_FLAGS", "")
     env["XLA_FLAGS"] = (
         f"{flags} --xla_force_host_platform_device_count={CS_SHARDS}".strip()

@@ -11,6 +11,7 @@ import sys
 import time
 
 from benchmarks.common import emit
+from repro.utils.compile_cache import use_compile_cache
 
 MODULES = ["table1_robustness", "table2_detection", "fig2_convergence",
            "fig3_aggregation_time", "round_engine", "fused_engine",
@@ -22,6 +23,7 @@ def main() -> int:
     ap.add_argument("--quick", action="store_true", help="reduced sizes/rounds")
     ap.add_argument("--only", default=None, help="comma-separated module prefixes")
     args = ap.parse_args()
+    use_compile_cache()
 
     only = args.only.split(",") if args.only else None
     print("name,us_per_call,derived")

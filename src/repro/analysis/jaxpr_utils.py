@@ -11,10 +11,7 @@ from typing import Any, Callable, Iterator
 
 import jax
 
-try:  # jax >= 0.4.16 exports the IR types via jax.extend
-    from jax.extend.core import ClosedJaxpr, Jaxpr, Var
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import ClosedJaxpr, Jaxpr, Var  # type: ignore[attr-defined]
+from jax.extend.core import ClosedJaxpr, Jaxpr, Var
 
 
 def as_jaxpr(obj: Any) -> Jaxpr:

@@ -3,7 +3,8 @@
 Replaces the ad-hoc jaxpr string asserts formerly duplicated across
 ``tests/test_afa_screen.py`` and ``benchmarks/fused_engine.py`` with one
 API: trace the entry point, enumerate its ``pallas_call`` eqns (launch names
-come from the kernel body's ``__name__`` recorded in ``name_and_src_info``),
+come from the ``name`` every kernel in ``repro.kernels`` passes to
+``pallas_call`` — its body's ``__name__``),
 and compare against a :class:`LaunchBudget`.
 """
 
@@ -46,10 +47,10 @@ class LaunchBudget(NamedTuple):
         return True
 
 
-def _launch_name(eqn: Any) -> str:
-    info = eqn.params.get("name_and_src_info")
-    name = getattr(info, "name", None)
-    return name if name else str(eqn.params.get("name", "<pallas_call>"))
+def launch_name(eqn: Any) -> str:
+    """The ``name`` a ``pallas_call`` eqn was given (``<pallas_call>`` for
+    an unnamed launch outside this package)."""
+    return eqn.params.get("name") or "<pallas_call>"
 
 
 def pallas_launch_names(fn_or_jaxpr: Any, *args: Any) -> list[str]:
@@ -59,7 +60,7 @@ def pallas_launch_names(fn_or_jaxpr: Any, *args: Any) -> list[str]:
     arguments (traced here, never executed).
     """
     jx = trace(fn_or_jaxpr, *args) if callable(fn_or_jaxpr) else fn_or_jaxpr
-    return [_launch_name(e) for e in eqns_by_primitive(jx, "pallas_call")]
+    return [launch_name(e) for e in eqns_by_primitive(jx, "pallas_call")]
 
 
 def count_pallas_launches(fn_or_jaxpr: Any, *args: Any) -> int:

@@ -34,6 +34,7 @@ from repro.analysis.jaxpr_utils import (
     subjaxprs,
     trace,
 )
+from repro.analysis.launches import launch_name
 from repro.analysis.report import Finding, error, warning
 from repro.kernels.meta import kernel_geometry
 
@@ -107,8 +108,7 @@ def _eval_index_map(closed: Any, step: tuple[int, ...]) -> tuple[int, ...]:
 def analyze_pallas_eqn(eqn: Any, step_cap: int = 4096) -> list[OutputAccess]:
     """Derived per-output access patterns for one ``pallas_call`` eqn."""
     gm = eqn.params["grid_mapping"]
-    info = eqn.params.get("name_and_src_info")
-    name = getattr(info, "name", "<pallas_call>")
+    name = launch_name(eqn)
     grid = tuple(gm.grid)
     if any(not isinstance(g, int) for g in grid):
         # dynamic grid: cannot enumerate; report as truncated with 0 steps

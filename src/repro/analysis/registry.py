@@ -406,7 +406,6 @@ def _check_collective_budget(report: Report, scope: LintScope) -> None:
         return
 
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.analysis.collectives import (
@@ -430,11 +429,11 @@ def _check_collective_budget(report: Report, scope: LintScope) -> None:
         return (r.aggregate, r.good_mask, r.rounds, r.similarities)
 
     spec = P(axis)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, spec, spec, spec),
         out_specs=(P(), spec, P(), spec),
-        check_rep=False,
+        check_vma=False,
     )
     # scalar_elements=4 sits above the 3-element mean/var/count stats psum
     # and below anything scaling with K or d, so the lint workload's small

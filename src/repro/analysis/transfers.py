@@ -21,6 +21,7 @@ HOST_BOUNDARY_PRIMITIVES = frozenset({
     "pure_callback",
     "io_callback",
     "debug_callback",
+    "debug_print",
     "callback",
     "outside_call",
     "infeed",

@@ -31,6 +31,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.flatten_util import ravel_pytree
 
 UPDATE_ATTACK_SCENARIOS = ("byzantine", "alie", "ipm")
 
@@ -140,6 +141,14 @@ def byzantine_update_tree(
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
+def _psum_fused(tree, axis_name):
+    """``psum`` an f32 pytree as ONE flat vector: ``jax.lax.psum`` of a
+    pytree binds one collective per leaf, so the leaves are raveled into a
+    single buffer first (elementwise sums, so the values are unchanged)."""
+    flat, unravel = ravel_pytree(tree)
+    return unravel(jax.lax.psum(flat, axis_name))
+
+
 def alie_update_tree(
     proposals, bad_mask, benign_mask, *, z_max: float = 1.2, axis_name=None
 ):
@@ -148,8 +157,8 @@ def alie_update_tree(
     With ``axis_name`` the proposal stack is client-sharded over that mesh
     axis and the benign moments are made global with ONE fused collective:
     the per-leaf partial sums, partial sums of squares, and the benign count
-    travel together in a single ``jax.lax.psum`` of one pytree (one
-    ``psum_p`` bind -> one collective per attack), then the variance is
+    travel together in a single ``psum`` of one flat f32 vector
+    (:func:`_psum_fused`, one collective per attack), then the variance is
     assembled in the one-pass form ``E[x²] − E[x]²`` (clamped at 0 against
     cancellation).  The unsharded path keeps the original two-pass
     computation bit for bit."""
@@ -172,7 +181,7 @@ def alie_update_tree(
         s1.append(jnp.sum(w * lf, axis=0))
         s2.append(jnp.sum(w * lf * lf, axis=0))
     cnt_local = jnp.sum(benign_mask.astype(jnp.float32))
-    s1, s2, cnt = jax.lax.psum((s1, s2, cnt_local), axis_name)
+    s1, s2, cnt = _psum_fused((s1, s2, cnt_local), axis_name)
     cnt = jnp.maximum(cnt, 1.0)
     out = []
     for l, a, b in zip(leaves, s1, s2):
@@ -207,7 +216,7 @@ def ipm_update_tree(
         for l in leaves
     ]
     cnt_local = jnp.sum(benign_mask.astype(jnp.float32))
-    s1, cnt = jax.lax.psum((s1, cnt_local), axis_name)
+    s1, cnt = _psum_fused((s1, cnt_local), axis_name)
     cnt = jnp.maximum(cnt, 1.0)
     out = [
         jnp.where(_row(bad_mask, l), (-eps * (a / cnt)).astype(l.dtype)[None], l)

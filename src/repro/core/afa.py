@@ -48,7 +48,15 @@ from repro.core.stats import masked_mean, masked_median, masked_std
 from repro.kernels.policy import resolve_kernel_mode
 from repro.utils.trees import tree_dot
 
+# every aggregation contraction runs at full f32: on the TPU the default
+# precision rounds f32 matmul operands to bf16 (the kernels match this)
+HIGHEST = jax.lax.Precision.HIGHEST
+
 EPS = 1e-12
+# similarities closer to the median than this share of it are ties: the
+# spread the tail test scales ``xi`` by is floored at SIM_TIE_RTOL * |median|,
+# so rows that differ only by f32 rounding in the cosine are never cut
+SIM_TIE_RTOL = 2.0**-20
 
 # Lazy module-level accessor for the kernel ops (satisfies the one-time
 # import contract: resolve_kernel_mode is imported at module scope above —
@@ -129,7 +137,7 @@ def _mark_bad(s, mask, xi, ddof):
     """One Algorithm-1 screening pass: returns the newly-bad mask."""
     mu_hat = masked_mean(s, mask)
     mu_bar = masked_median(s, mask)
-    sigma = masked_std(s, mask, ddof=ddof)
+    sigma = jnp.maximum(masked_std(s, mask, ddof=ddof), SIM_TIE_RTOL * jnp.abs(mu_bar))
     low_tail = mask & (s < mu_bar - xi * sigma)
     high_tail = mask & (s > mu_bar + xi * sigma)
     bad = jnp.where(mu_hat < mu_bar, low_tail, high_tail)
@@ -203,11 +211,17 @@ def afa_aggregate(
         if mode != "jnp":
             gram = _kernel_ops().gram(upd32, interpret=interp)
         else:
-            gram = upd32 @ upd32.T  # (K, K) — single pass over d
+            gram = jnp.matmul(upd32, upd32.T, precision=HIGHEST)  # (K, K)
 
         def sims(c):
-            gc = gram @ c
-            agg_norm = jnp.sqrt(jnp.maximum(c @ gc, EPS))
+            # row-vector form of G c and c.Gc: the op sequence the fused
+            # kernel must use on Mosaic (no 1-D matvec there), kept in
+            # lockstep with kernels/afa_screen._screen
+            gc = jax.lax.dot_general(
+                c[None, :], gram, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=HIGHEST,
+            )[0]
+            agg_norm = jnp.sqrt(jnp.maximum(jnp.sum(c * gc), EPS))
             return gc / (jnp.maximum(row_norms, EPS) * agg_norm)
 
     elif mode != "jnp":
@@ -220,9 +234,9 @@ def afa_aggregate(
     else:
 
         def sims(c):
-            agg = c @ upd32  # (d,)
+            agg = jnp.matmul(c, upd32, precision=HIGHEST)  # (d,)
             agg_norm = jnp.linalg.norm(agg)
-            return (upd32 @ agg) / (
+            return jnp.matmul(upd32, agg, precision=HIGHEST) / (
                 jnp.maximum(row_norms, EPS) * jnp.maximum(agg_norm, EPS)
             )
 
@@ -252,7 +266,7 @@ def afa_aggregate(
     if mode != "jnp":
         agg = _kernel_ops().weighted_sum(w, upd32, interpret=interp).astype(updates.dtype)
     else:
-        agg = (w @ upd32).astype(updates.dtype)
+        agg = jnp.matmul(w, upd32, precision=HIGHEST).astype(updates.dtype)
     return AFAResult(aggregate=agg, good_mask=mask, rounds=rounds, similarities=s)
 
 
@@ -307,9 +321,11 @@ def _afa_aggregate_sharded(updates, upd32, n_k, p_k, mask0, config, mode, interp
     else:
 
         def sims(c):
-            w_agg = jax.lax.psum(_local(c) @ upd32, axis)  # (d,)
+            w_agg = jax.lax.psum(
+                jnp.matmul(_local(c), upd32, precision=HIGHEST), axis
+            )  # (d,)
             agg_norm = jnp.linalg.norm(w_agg)
-            s_l = (upd32 @ w_agg) / (
+            s_l = jnp.matmul(upd32, w_agg, precision=HIGHEST) / (
                 jnp.maximum(row_norms_l, EPS) * jnp.maximum(agg_norm, EPS)
             )
             return jax.lax.all_gather(s_l, axis, tiled=True)
@@ -334,7 +350,8 @@ def _afa_aggregate_sharded(updates, upd32, n_k, p_k, mask0, config, mode, interp
                          lambda _: jnp.zeros((3,), jnp.float32), None),
             axis,
         )
-        mu_hat, mu_bar, sigma = stats[0], stats[1], stats[2]
+        mu_hat, mu_bar = stats[0], stats[1]
+        sigma = jnp.maximum(stats[2], SIM_TIE_RTOL * jnp.abs(mu_bar))
         low_tail = mask & (s < mu_bar - xi * sigma)
         high_tail = mask & (s > mu_bar + xi * sigma)
         bad = jnp.where(mu_hat < mu_bar, low_tail, high_tail)
@@ -363,7 +380,7 @@ def _afa_aggregate_sharded(updates, upd32, n_k, p_k, mask0, config, mode, interp
     if mode != "jnp":
         part = _kernel_ops().weighted_sum(w_l, upd32, interpret=interp)
     else:
-        part = w_l @ upd32
+        part = jnp.matmul(w_l, upd32, precision=HIGHEST)
     agg = jax.lax.psum(part, axis).astype(updates.dtype)
     return AFAResult(
         aggregate=agg, good_mask=_local(mask), rounds=rounds,
@@ -406,7 +423,8 @@ def _stacked_gram(stacked):
     for l in jax.tree_util.tree_leaves(stacked):
         f = l.reshape(l.shape[0], -1)
         part = jax.lax.dot_general(
-            f, f, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            f, f, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=HIGHEST,
         )
         tot = part if tot is None else tot + part
     return tot
@@ -435,8 +453,8 @@ def afa_aggregate_tree(
         gram = _stacked_gram(stacked_updates)
 
         def sims(c):
-            gc = gram @ c
-            agg_norm = jnp.sqrt(jnp.maximum(c @ gc, EPS))
+            gc = jnp.matmul(gram, c, precision=HIGHEST)
+            agg_norm = jnp.sqrt(jnp.maximum(jnp.matmul(c, gc, precision=HIGHEST), EPS))
             return gc / (row_norms * agg_norm)
 
     else:

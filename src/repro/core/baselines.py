@@ -82,7 +82,7 @@ def _norm_weights(mask, w):
 
 def _weighted_rows(c, u32):
     """(K,) @ (K, d) -> (d,) on the jnp reference path."""
-    return (c @ u32).astype(jnp.float32)
+    return jnp.matmul(c, u32, precision=jax.lax.Precision.HIGHEST).astype(jnp.float32)
 
 
 def _weighted_rows_for(mode: str):
@@ -113,7 +113,7 @@ def pairwise_sq_dists(updates, *, use_kernels: bool | str = False):
 
         g = gram_kernel(u, interpret=(mode == "interpret"))
     else:
-        g = u @ u.T
+        g = jnp.matmul(u, u.T, precision=jax.lax.Precision.HIGHEST)
     sq = jnp.diag(g)
     d2 = sq[:, None] + sq[None, :] - 2.0 * g
     return jnp.maximum(d2, 0.0)

@@ -35,7 +35,9 @@ def geometric_median_aggregate(
     def step(v, _):
         dist = jnp.sqrt(jnp.sum((u - v[None]) ** 2, axis=1) + EPS)
         w = jnp.where(mask, 1.0 / dist, 0.0)
-        v_new = (w @ u) / jnp.maximum(jnp.sum(w), EPS)
+        v_new = jnp.matmul(w, u, precision=jax.lax.Precision.HIGHEST) / jnp.maximum(
+            jnp.sum(w), EPS
+        )
         return v_new, None
 
     v, _ = jax.lax.scan(step, v0, None, length=iters)
@@ -98,7 +100,8 @@ def zeno_aggregate(
     ranks = jnp.zeros((K,), jnp.int32).at[order].set(jnp.arange(K, dtype=jnp.int32))
     keep = (ranks < num_keep) & mask
     c = _norm_weights(keep, jnp.ones((K,), jnp.float32))
-    return AggResult((c @ updates.astype(jnp.float32)).astype(updates.dtype), keep)
+    agg = jnp.matmul(c, updates.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+    return AggResult(agg.astype(updates.dtype), keep)
 
 
 # Registry hookup.  No Pallas kernel covers the Weiszfeld / clipping

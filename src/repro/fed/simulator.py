@@ -83,6 +83,7 @@ from repro.fed.engine import (
     make_fused_segment,
     make_fused_sim,
     make_train_attack_step,
+    place_on_client_mesh,
     sweep_fused_sim,
 )
 from repro.fed.server import (
@@ -144,6 +145,10 @@ class SimResult:
                                 # aggregate + eval dispatch (host engines eval
                                 # in-loop, symmetric with the fused scan)
     round_times: list = dataclasses.field(default_factory=list)  # raw per-round
+    params: object = None       # global parameters after the last round
+    # fused engines: AFA's final-iteration cosine similarities, one (K,)
+    # row per round (zeros for rules without them; empty elsewhere)
+    similarity_history: list = dataclasses.field(default_factory=list)
 
 
 class _Setup:
@@ -224,7 +229,8 @@ class _Setup:
         )
 
     def result(self, blocked_round: np.ndarray, test_error, good_hist,
-               t_train, t_agg, round_times) -> SimResult:
+               t_train, t_agg, round_times, params=None,
+               sim_hist=()) -> SimResult:
         sim, bad = self.sim, self.bad
         rate, mean_rounds = detection_stats(blocked_round, bad)
         return SimResult(
@@ -238,6 +244,8 @@ class _Setup:
             mean_rounds_to_block=mean_rounds,
             round_time=float(np.mean(round_times)) if round_times else 0.0,
             round_times=list(round_times),
+            params=params,
+            similarity_history=list(sim_hist),
         )
 
 
@@ -372,7 +380,8 @@ def _run_batched(setup: _Setup, server_cfg: ServerConfig, eval_every: int) -> Si
         round_times.append(time.perf_counter() - t_start)
 
     return setup.result(
-        server.rounds_blocked, test_error, good_hist, t_train, t_agg, round_times
+        server.rounds_blocked, test_error, good_hist, t_train, t_agg, round_times,
+        params,
     )
 
 
@@ -437,7 +446,8 @@ def _run_looped(setup: _Setup, server_cfg: ServerConfig, eval_every: int) -> Sim
         round_times.append(time.perf_counter() - t_start)
 
     return setup.result(
-        server.rounds_blocked, test_error, good_hist, t_train, t_agg, round_times
+        server.rounds_blocked, test_error, good_hist, t_train, t_agg, round_times,
+        params,
     )
 
 
@@ -562,10 +572,10 @@ def _run_fused(
         for rnd in range(sim.rounds):
             carry, out = step(carry, jnp.int32(rnd), jnp.uint32(sim.seed), data)
             outs.append(out)
-        state = carry[1]
+        params, state = carry
         traj = FusedTrajectory(*[jnp.stack(ls) for ls in zip(*outs)])
     else:
-        _, state, traj = scan_fn(setup.params0, jnp.uint32(sim.seed), data)
+        params, state, traj = scan_fn(setup.params0, jnp.uint32(sim.seed), data)
     jax.block_until_ready(traj)
     total = time.perf_counter() - t_start
 
@@ -581,6 +591,7 @@ def _run_fused(
     return setup.result(
         np.asarray(state.rounds_blocked), test_error, good_hist,
         0.0, 0.0, [per_round] * sim.rounds,
+        params, list(np.asarray(traj.similarities)),
     )
 
 
@@ -641,6 +652,63 @@ def _segment_fn(setup: _Setup, server_cfg: ServerConfig, seg_len: int,
     )
 
 
+def _segment_layout(live: np.ndarray, K: int, n_shards: int, mesh):
+    """``(kept, bucket)`` of a segment: the index map of the clients a
+    segment carries and its row count.  Unsharded, the live ids fill a pow2
+    bucket; client-sharded, compaction is per shard — equal pow2 blocks with
+    ``-1`` pads at block tails (``data/sharding.shard_compact_plan``)."""
+    if mesh is None:
+        return live, pow2_bucket(len(live), K)
+    kept, rows = shard_compact_plan(live, n_shards, K // n_shards)
+    return kept, rows * n_shards
+
+
+def _segment_inputs(setup: _Setup, params, state_full, kept, bucket: int, mesh):
+    """A segment's inputs for the ``(kept, bucket)`` layout: the compacted
+    data stacks, masks and server state, with params.  Client-sharded, all
+    of them are committed to the client mesh
+    (:func:`~repro.fed.engine.place_on_client_mesh`)."""
+    data_c, bad_c, ids_c = _compact_inputs(setup, kept, bucket)
+    state_c = gather_server_state(state_full, kept, bucket)
+    if mesh is None:
+        return params, state_c, data_c, bad_c, ids_c
+    return place_on_client_mesh(mesh, params, state_c, data_c, bad_c, ids_c)
+
+
+def first_segment(
+    data: SyntheticClassification, sim: SimConfig, server_cfg: ServerConfig
+):
+    """The program the segmented fused engine runs first, and its arguments.
+
+    Returns ``(segment_fn, args)``: ``segment_fn(*args)`` is the first
+    segment call that :func:`simulate` makes for ``sim`` (``engine="fused"``,
+    ``segment_rounds > 0``) — the same cached jit and the same argument
+    shapes and placement.  A caller can compile it ahead of the run
+    (``segment_fn.lower(*args).compile()``), inspect the compiled program,
+    and the run then reuses the executable.
+    """
+    if sim.engine != "fused" or sim.segment_rounds <= 0:
+        raise ValueError("first_segment needs engine='fused' and segment_rounds > 0")
+    setup = _Setup(data, sim)
+    K = sim.num_clients
+    mesh = _client_mesh(sim)
+    n_shards = max(sim.client_shards, 1) if mesh is not None else 1
+    kept, bucket = _segment_layout(np.arange(K), K, n_shards, mesh)
+    params, state_c, data_c, bad_c, ids_c = _segment_inputs(
+        setup, setup.params0,
+        init_server_state(K, server_cfg.alpha0, server_cfg.beta0),
+        kept, bucket, mesh,
+    )
+    seg_fn = _segment_fn(
+        setup, server_cfg, min(sim.segment_rounds, sim.rounds), mesh,
+        None if mesh is None else bucket // n_shards,
+    )
+    return seg_fn, (
+        params, state_c, jnp.uint32(sim.seed), data_c, bad_c, ids_c,
+        jnp.int32(0),
+    )
+
+
 def _run_fused_segmented(
     setup: _Setup, server_cfg: ServerConfig, eval_every: int
 ) -> SimResult:
@@ -673,6 +741,7 @@ def _run_fused_segmented(
 
     test_error = np.zeros((T,), np.float64)
     good = np.zeros((T, K), bool)
+    sims = np.zeros((T, K), np.float32)
     round_times = np.zeros((T,), np.float64)
 
     params = setup.params0
@@ -696,12 +765,7 @@ def _run_fused_segmented(
             live = kept[~blocked_c & (kept >= 0)]
         else:
             live = np.arange(K)
-        if mesh is None:
-            new_bucket, new_kept = pow2_bucket(len(live), K), live
-        else:
-            # per-shard compaction: equal pow2 blocks, -1 pads at block tails
-            new_kept, rows = shard_compact_plan(live, n_shards, K // n_shards)
-            new_bucket = rows * n_shards
+        new_kept, new_bucket = _segment_layout(live, K, n_shards, mesh)
         if bucket != new_bucket:
             # bucket boundary crossed: preserve the rows being dropped, then
             # compact to the smaller layout (the first iteration lands here
@@ -709,8 +773,9 @@ def _run_fused_segmented(
             if bucket is not None:
                 state_full = scatter_server_state(state_full, state_c, kept)
             bucket, kept = new_bucket, new_kept
-            data_c, bad_c, ids_c = _compact_inputs(setup, kept, bucket)
-            state_c = gather_server_state(state_full, kept, bucket)
+            params, state_c, data_c, bad_c, ids_c = _segment_inputs(
+                setup, params, state_full, kept, bucket, mesh
+            )
         seg_fn = _segment_fn(
             setup, server_cfg, seg_len, mesh,
             None if mesh is None else bucket // n_shards,
@@ -729,6 +794,9 @@ def _run_fused_segmented(
         good[seg_start:end, kept[valid]] = (
             np.asarray(traj.good_mask)[:, np.nonzero(valid)[0]]
         )
+        sims[seg_start:end, kept[valid]] = (
+            np.asarray(traj.similarities)[:, np.nonzero(valid)[0]]
+        )
         round_times[seg_start:end] = (time.perf_counter() - t0) / seg_len
         seg_start = end
 
@@ -740,7 +808,7 @@ def _run_fused_segmented(
     good_hist = [gm for gm in good]
     return setup.result(
         np.asarray(state_full.rounds_blocked), test_error_list, good_hist,
-        0.0, 0.0, list(round_times),
+        0.0, 0.0, list(round_times), params, list(sims),
     )
 
 

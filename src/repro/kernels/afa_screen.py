@@ -55,6 +55,10 @@ deviation is the masked median: ``jnp.sort`` has no Mosaic lowering, so it
 is computed by compare-count rank selection (the ``coord_median`` idiom).
 That selects the *same two order statistics* the sort would (ties broken by
 index pick equal values), so the result is value-identical.
+
+Every contraction sets ``precision=HIGHEST``, as the jnp reference does:
+at Mosaic's default an f32 matmul rounds its operands to bf16 on the MXU,
+which moved the aggregate ~3e-4 (relative) off the f32 rule on a v5e.
 """
 
 from __future__ import annotations
@@ -68,43 +72,56 @@ from jax.experimental import pallas as pl
 from repro.kernels.meta import register_kernel_geometry
 
 EPS = 1e-12  # must match core/afa.py
+SIM_TIE_RTOL = 2.0**-20  # must match core/afa.py
 
 
-def _masked_mean(x, mask):
-    """Mirror of core.stats.masked_mean (same ops, same order)."""
-    m = jnp.sum(mask)
-    return jnp.where(m > 0, jnp.sum(jnp.where(mask, x, 0.0)) / jnp.maximum(m, 1), 0.0)
+def _row_to_col(x, eye):
+    """(1, K) row -> (K, 1) column by a masked lane reduction (exact: one
+    live term per row).  Mosaic has no cheap relayout of a 1-row vector into
+    a column, so this selects through the identity instead."""
+    return jnp.sum(jnp.where(eye, x, jnp.zeros_like(x)), axis=1, keepdims=True)
 
 
-def _masked_std(x, mask, ddof):
+def _col_to_row(x, eye):
+    """(K, 1) column -> (1, K) row, the transpose of :func:`_row_to_col`."""
+    return jnp.sum(jnp.where(eye, x, jnp.zeros_like(x)), axis=0, keepdims=True)
+
+
+def _masked_mean(x, m, live):
+    """Mirror of core.stats.masked_mean on a (1, K) row; ``live`` is the
+    0/1 int32 mask row and ``m`` its sum."""
+    return jnp.where(
+        m > 0, jnp.sum(jnp.where(live != 0, x, 0.0)) / jnp.maximum(m, 1), 0.0
+    )
+
+
+def _masked_std(x, m, live, ddof):
     """Mirror of core.stats.masked_std."""
-    m = jnp.sum(mask)
-    mu = _masked_mean(x, mask)
-    var = jnp.sum(jnp.where(mask, (x - mu) ** 2, 0.0)) / jnp.maximum(m - ddof, 1)
+    mu = _masked_mean(x, m, live)
+    var = jnp.sum(jnp.where(live != 0, (x - mu) ** 2, 0.0)) / jnp.maximum(m - ddof, 1)
     return jnp.sqrt(jnp.maximum(var, 0.0))
 
 
-def _masked_median_cc(x, mask):
+def _masked_median_cc(x, m, live, eye, after):
     """core.stats.masked_median by compare-count rank selection.
 
     ``jnp.sort`` has no Mosaic lowering; ranking each live element against
-    the live set (ties broken by index → a strict total order) and summing
+    the live set (ties broken by index -> a strict total order) and summing
     the one-hot selections of ranks ``(m-1)//2`` and ``m//2`` picks the same
     two order-statistic VALUES the sort-based reference picks, so the
-    average is value-identical (O(K²) compares — VPU change for K scalars).
+    average is value-identical (O(K^2) compares).  Element i lives on the
+    sublane axis (the column copy of ``x``), element k on the lane axis.
     """
-    K = x.shape[0]
-    m = jnp.sum(mask)
-    live = mask[None, :]
-    lt = (x[None, :] < x[:, None]) & live
-    ii = jax.lax.broadcasted_iota(jnp.int32, (K, K), 0)
-    kk = jax.lax.broadcasted_iota(jnp.int32, (K, K), 1)
-    eq = (x[None, :] == x[:, None]) & (ii > kk) & live
-    rank = jnp.sum(lt.astype(jnp.int32) + eq.astype(jnp.int32), axis=1)
+    x_col = _row_to_col(x, eye)
+    live_col = _row_to_col(live, eye)
+    lt = (x < x_col) & (live != 0)
+    eq = (x == x_col) & after & (live != 0)
+    rank = jnp.sum(lt.astype(jnp.int32) + eq.astype(jnp.int32), axis=1,
+                   keepdims=True)
     lo = jnp.maximum((m - 1) // 2, 0)
     hi = jnp.maximum(m // 2, 0)
-    v_lo = jnp.sum(jnp.where(mask & (rank == lo), x, 0.0))
-    v_hi = jnp.sum(jnp.where(mask & (rank == hi), x, 0.0))
+    v_lo = jnp.sum(jnp.where((live_col != 0) & (rank == lo), x_col, 0.0))
+    v_hi = jnp.sum(jnp.where((live_col != 0) & (rank == hi), x_col, 0.0))
     return jnp.where(m > 0, 0.5 * (v_lo + v_hi), 0.0)
 
 
@@ -113,48 +130,60 @@ def _screen(gram, unorm2, pn, mask0, *, xi0, delta_xi, max_rounds, ddof):
 
     Mirror of the ``variant="gram"`` while-loop in ``core/afa.py`` — any
     change there must land here too (the parity suite asserts bitwise
-    equality on the interpret route).  Returns ``(weights, mask, rounds,
-    sims)`` with ``weights`` the final normalized reputation weights.
+    equality on the interpret route).  Every operand stays 2-D for Mosaic:
+    ``gram`` (K, K), ``unorm2`` a (K, 1) column, ``pn`` a (1, K) f32 row and
+    ``mask0`` a (1, K) 0/1 int32 row; masks stay int32 so every select and
+    count is integer arithmetic.  Returns ``(weights, mask, rounds, sims)``
+    as (1, K) rows, ``weights`` the final normalized reputation weights.
     """
-    K = pn.shape[0]
-    row_norms = jnp.sqrt(unorm2)  # == jnp.linalg.norm(u, axis=1) bitwise
+    K = pn.shape[1]
+    ii = jax.lax.broadcasted_iota(jnp.int32, (K, K), 0)
+    kk = jax.lax.broadcasted_iota(jnp.int32, (K, K), 1)
+    eye, after = ii == kk, ii > kk
+    # == jnp.linalg.norm(u, axis=1) bitwise
+    row_norms = jnp.sqrt(_col_to_row(unorm2, eye))
 
     def weights(m):
-        c = jnp.where(m, pn, 0.0)
+        c = jnp.where(m != 0, pn, 0.0)
         return c / jnp.maximum(jnp.sum(c), EPS)
 
     def sims(c):
-        gc = gram @ c
-        agg_norm = jnp.sqrt(jnp.maximum(c @ gc, EPS))
+        gc = jax.lax.dot_general(
+            c, gram, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        agg_norm = jnp.sqrt(jnp.maximum(jnp.sum(c * gc), EPS))
         return gc / (jnp.maximum(row_norms, EPS) * agg_norm)
 
     def mark_bad(s, m, xi):
-        mu_hat = _masked_mean(s, m)
-        mu_bar = _masked_median_cc(s, m)
-        sigma = _masked_std(s, m, ddof)
-        low_tail = m & (s < mu_bar - xi * sigma)
-        high_tail = m & (s > mu_bar + xi * sigma)
-        bad = jnp.where(mu_hat < mu_bar, low_tail, high_tail)
-        keep_floor = jnp.sum(m & ~bad) >= 2
-        return jnp.where(keep_floor, bad, jnp.zeros_like(bad))
+        count = jnp.sum(m)
+        mu_hat = _masked_mean(s, count, m)
+        mu_bar = _masked_median_cc(s, count, m, eye, after)
+        sigma = jnp.maximum(_masked_std(s, count, m, ddof), SIM_TIE_RTOL * jnp.abs(mu_bar))
+        low_tail = jnp.where(s < mu_bar - xi * sigma, m, 0)
+        high_tail = jnp.where(s > mu_bar + xi * sigma, m, 0)
+        low = (mu_hat < mu_bar).astype(jnp.int32)
+        bad = low * low_tail + (1 - low) * high_tail
+        keep_floor = (jnp.sum(m * (1 - bad)) >= 2).astype(jnp.int32)
+        return bad * keep_floor
 
     def cond(state):
         m, xi, changed, rounds, _ = state
-        return changed & (rounds < max_rounds)
+        return (changed > 0) & (rounds < max_rounds)
 
     def body(state):
         m, xi, _, rounds, _ = state
         s = sims(weights(m))
         bad = mark_bad(s, m, xi)
-        return (m & ~bad, xi + delta_xi, jnp.any(bad), rounds + 1, s)
+        return (m * (1 - bad), xi + delta_xi, jnp.sum(bad), rounds + 1, s)
 
     s0 = (
         sims(weights(mask0)) if max_rounds == 0
-        else jnp.zeros((K,), jnp.float32)
+        else jnp.zeros((1, K), jnp.float32)
     )
     mask, _, _, rounds, s = jax.lax.while_loop(
         cond, body,
-        (mask0, jnp.float32(xi0), jnp.bool_(True), jnp.int32(0), s0),
+        (mask0, jnp.float32(xi0), jnp.int32(1), jnp.int32(0), s0),
     )
     return weights(mask), mask, rounds, s
 
@@ -164,17 +193,21 @@ def _afa_screen_onepass_kernel(u_ref, pn_ref, mask_ref, agg_ref, good_ref, round
     """Single grid step: gram + screening + aggregate on one resident tile."""
     u = u_ref[...].astype(jnp.float32)
     gram = jax.lax.dot_general(
-        u, u, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        u, u, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
-    unorm2 = jnp.sum(u * u, axis=1)
+    unorm2 = jnp.sum(u * u, axis=1, keepdims=True)
     w, mask, rounds, s = _screen(
-        gram, unorm2, pn_ref[0, :], mask_ref[0, :] != 0,
+        gram, unorm2, pn_ref[...], mask_ref[...],
         xi0=xi0, delta_xi=delta_xi, max_rounds=max_rounds, ddof=ddof,
     )
-    agg_ref[...] = (w @ u)[None, :]
-    good_ref[...] = mask.astype(jnp.int32)[None, :]
-    rounds_ref[...] = rounds[None, None]
-    sims_ref[...] = s[None, :]
+    agg_ref[...] = jax.lax.dot_general(
+        w, u, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    good_ref[...] = mask
+    rounds_ref[...] = jnp.full((1, 1), rounds, jnp.int32)
+    sims_ref[...] = s
 
 
 def _afa_screen_twopass_kernel(u_ref, pn_ref, mask_ref, agg_ref, good_ref, rounds_ref,
@@ -196,20 +229,21 @@ def _afa_screen_twopass_kernel(u_ref, pn_ref, mask_ref, agg_ref, good_ref, round
     def _accumulate():
         u = u_ref[...].astype(jnp.float32)
         g_ref[...] += jax.lax.dot_general(
-            u, u, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            u, u, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
-        un_ref[...] += jnp.sum(u * u, axis=1)[None, :]
+        un_ref[...] += jnp.sum(u * u, axis=1, keepdims=True)
 
     @pl.when((p == 0) & (b == nb - 1))
     def _screen_resident():
         w, mask, rounds, s = _screen(
-            g_ref[...], un_ref[0, :], pn_ref[0, :], mask_ref[0, :] != 0,
+            g_ref[...], un_ref[...], pn_ref[...], mask_ref[...],
             xi0=xi0, delta_xi=delta_xi, max_rounds=max_rounds, ddof=ddof,
         )
-        w_ref[...] = w[None, :]
-        good_ref[...] = mask.astype(jnp.int32)[None, :]
-        rounds_ref[...] = rounds[None, None]
-        sims_ref[...] = s[None, :]
+        w_ref[...] = w
+        good_ref[...] = mask
+        rounds_ref[...] = jnp.full((1, 1), rounds, jnp.int32)
+        sims_ref[...] = s
 
     @pl.when(p == 1)
     def _aggregate():
@@ -217,6 +251,7 @@ def _afa_screen_twopass_kernel(u_ref, pn_ref, mask_ref, agg_ref, good_ref, round
         agg_ref[...] = jax.lax.dot_general(
             w_ref[...], u, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
 
 
@@ -247,6 +282,7 @@ def afa_screen_call(
     if block_d is None or block_d >= d:
         agg, good, rounds, sims = pl.pallas_call(
             functools.partial(_afa_screen_onepass_kernel, **screen_kw),
+            name="_afa_screen_onepass_kernel",
             grid=(1,),
             in_specs=[
                 pl.BlockSpec((K, d), lambda i: (0, 0)),
@@ -265,11 +301,12 @@ def afa_screen_call(
     nb = d // block_d
     resident_shapes = (
         jax.ShapeDtypeStruct((K, K), jnp.float32),   # gram
-        jax.ShapeDtypeStruct((1, K), jnp.float32),   # unorm2
+        jax.ShapeDtypeStruct((K, 1), jnp.float32),   # unorm2
         jax.ShapeDtypeStruct((1, K), jnp.float32),   # final weights
     )
     agg, good, rounds, sims, _, _, _ = pl.pallas_call(
         functools.partial(_afa_screen_twopass_kernel, nb=nb, **screen_kw),
+        name="_afa_screen_twopass_kernel",
         grid=(2, nb),
         in_specs=[
             pl.BlockSpec((K, block_d), lambda p, b: (0, b)),
@@ -285,7 +322,7 @@ def afa_screen_call(
             pl.BlockSpec((1, 1), lambda p, b: (0, 0)),
             pl.BlockSpec((1, K), lambda p, b: (0, 0)),
             pl.BlockSpec((K, K), lambda p, b: (0, 0)),
-            pl.BlockSpec((1, K), lambda p, b: (0, 0)),
+            pl.BlockSpec((K, 1), lambda p, b: (0, 0)),
             pl.BlockSpec((1, K), lambda p, b: (0, 0)),
         ),
         out_shape=out_shapes + resident_shapes,

@@ -77,6 +77,7 @@ def coord_median(
     if mask is None:
         out = pl.pallas_call(
             functools.partial(_coord_median_kernel, K=K),
+            name="_coord_median_kernel",
             grid=(d // block_d,),
             in_specs=[pl.BlockSpec((K, block_d), lambda b: (0, b))],
             out_specs=pl.BlockSpec((1, block_d), lambda b: (0, b)),
@@ -86,6 +87,7 @@ def coord_median(
         return out[0]
     out = pl.pallas_call(
         functools.partial(_coord_median_masked_kernel, K=K),
+        name="_coord_median_masked_kernel",
         grid=(d // block_d,),
         in_specs=[
             pl.BlockSpec((K, block_d), lambda b: (0, b)),

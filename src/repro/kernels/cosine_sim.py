@@ -12,8 +12,8 @@ output accumulators:
 
 TPU grid iterations are sequential, so read-modify-write accumulation on the
 outputs is safe; the final divide happens in ops.py (O(K), negligible).
-The dot itself maps to the MXU (K×BLOCK_D @ BLOCK_D×1 as a matmul with the
-aggregate tile broadcast), the squares to the VPU.
+The dots and the squares are elementwise products and lane reductions on
+the VPU, in f32 (no MXU, so no bf16 operand rounding).
 
 Packed-operand contract (ops.py): d is the FULL packed model width, zero-
 padded to a BLOCK_D multiple, and K arrives zero-padded to the 8-row f32
@@ -63,6 +63,7 @@ def cosine_sim_parts(
     )
     return pl.pallas_call(
         _cosine_sim_kernel,
+        name="_cosine_sim_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((K, block_d), lambda b: (0, b)),

@@ -103,6 +103,7 @@ def flash_attention_bh(
         functools.partial(
             _flash_attn_kernel, block_q=block_q, block_k=block_k, causal=causal, lk=lk
         ),
+        name="_flash_attn_kernel",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),

@@ -13,6 +13,9 @@ one-shot "gram" variant of AFA.  Two layouts over the packed (K, D) operand:
   sequential).  For packed stacks too wide for a VMEM-resident (K, K)
   accumulator.
 
+Both accumulate at ``precision=HIGHEST`` (Mosaic's default rounds f32
+operands to bf16 on the MXU).
+
 ops.py zero-pads K to the block/sublane multiple — zero rows contribute zero
 dot products, so the padded Gram rows/columns are sliced off exactly.
 """
@@ -35,7 +38,8 @@ def _gram_kernel(u_ref, g_ref):
 
     u = u_ref[...].astype(jnp.float32)
     g_ref[...] += jax.lax.dot_general(
-        u, u, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        u, u, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
@@ -49,7 +53,8 @@ def _gram_kernel_tiled(ui_ref, uj_ref, g_ref):
     ui = ui_ref[...].astype(jnp.float32)  # (BK, BD) row block i
     uj = uj_ref[...].astype(jnp.float32)  # (BK, BD) row block j
     g_ref[...] += jax.lax.dot_general(
-        ui, uj, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ui, uj, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
@@ -65,6 +70,7 @@ def gram(
     if block_k is None or block_k >= K:
         return pl.pallas_call(
             _gram_kernel,
+            name="_gram_kernel",
             grid=(d // block_d,),
             in_specs=[pl.BlockSpec((K, block_d), lambda b: (0, b))],
             out_specs=pl.BlockSpec((K, K), lambda b: (0, 0)),
@@ -74,6 +80,7 @@ def gram(
     assert K % block_k == 0, (K, block_k)
     return pl.pallas_call(
         _gram_kernel_tiled,
+        name="_gram_kernel_tiled",
         grid=(K // block_k, K // block_k, d // block_d),
         in_specs=[
             pl.BlockSpec((block_k, block_d), lambda i, j, b: (i, b)),

@@ -5,16 +5,18 @@ which sweeps shapes/dtypes and asserts allclose against these)."""
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 EPS = 1e-12
+HIGHEST = jax.lax.Precision.HIGHEST  # full f32, as the kernels contract
 
 
 def cosine_sim_ref(updates: jnp.ndarray, agg: jnp.ndarray) -> jnp.ndarray:
     """(K, d), (d,) -> (K,) cosine similarities in f32."""
     u = updates.astype(jnp.float32)
     w = agg.astype(jnp.float32)
-    dots = u @ w
+    dots = jnp.matmul(u, w, precision=HIGHEST)
     un = jnp.linalg.norm(u, axis=1)
     wn = jnp.linalg.norm(w)
     return dots / (jnp.maximum(un, EPS) * jnp.maximum(wn, EPS))
@@ -23,7 +25,7 @@ def cosine_sim_ref(updates: jnp.ndarray, agg: jnp.ndarray) -> jnp.ndarray:
 def gram_ref(updates: jnp.ndarray) -> jnp.ndarray:
     """(K, d) -> (K, K) Gram matrix in f32."""
     u = updates.astype(jnp.float32)
-    return u @ u.T
+    return jnp.matmul(u, u.T, precision=HIGHEST)
 
 
 def coord_median_ref(updates: jnp.ndarray) -> jnp.ndarray:
@@ -34,13 +36,13 @@ def coord_median_ref(updates: jnp.ndarray) -> jnp.ndarray:
 
 def weighted_sum_ref(updates: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
     """(K, d), (K,) -> (d,) weighted sum in f32."""
-    return weights.astype(jnp.float32) @ updates.astype(jnp.float32)
+    return jnp.matmul(
+        weights.astype(jnp.float32), updates.astype(jnp.float32), precision=HIGHEST
+    )
 
 
 def flash_attention_ref(q, k, v, *, causal: bool = True) -> jnp.ndarray:
     """(B, Lq, Hq, D), (B, Lk, Hkv, D) x2 -> (B, Lq, Hq, D), exact softmax."""
-    import jax
-
     b, lq, hq, d = q.shape
     _, lk, hkv, _ = k.shape
     g = hq // hkv

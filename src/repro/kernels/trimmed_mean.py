@@ -61,6 +61,7 @@ def trimmed_mean(
     assert d % block_d == 0, (d, block_d)
     out = pl.pallas_call(
         functools.partial(_trimmed_mean_kernel, K=K, trim=trim),
+        name="_trimmed_mean_kernel",
         grid=(d // block_d,),
         in_specs=[
             pl.BlockSpec((K, block_d), lambda b: (0, b)),

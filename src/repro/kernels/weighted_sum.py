@@ -1,7 +1,8 @@
 """Reputation-weighted aggregation kernel: w_agg = c @ U.
 
 The write path of AFA's eq. (3): a (1, K) x (K, BLOCK_D) matvec per tile,
-grid over d.  Exists mostly so the whole robust-aggregation pipeline
+grid over d, at ``precision=HIGHEST`` (Mosaic's default rounds f32
+operands to bf16 on the MXU).  Exists mostly so the whole robust-aggregation pipeline
 (gram/cosine -> while-loop on scalars -> weighted sum) can run on-chip without
 bouncing the update matrix through HBM more than twice.
 
@@ -23,7 +24,8 @@ def _weighted_sum_kernel(c_ref, u_ref, out_ref):
     c = c_ref[...].astype(jnp.float32)  # (1, K)
     u = u_ref[...].astype(jnp.float32)  # (K, BD)
     out_ref[...] = jax.lax.dot_general(
-        c, u, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        c, u, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
@@ -38,6 +40,7 @@ def weighted_sum(
     assert d % block_d == 0, (d, block_d)
     out = pl.pallas_call(
         _weighted_sum_kernel,
+        name="_weighted_sum_kernel",
         grid=(d // block_d,),
         in_specs=[
             pl.BlockSpec((1, K), lambda b: (0, 0)),

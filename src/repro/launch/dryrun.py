@@ -116,8 +116,6 @@ def run_one(arch: str, shape_name: str, mesh, mesh_tag: str, out_dir: str,
 
         mem = compiled.memory_analysis()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # jax<=0.4.x returns [dict]
-            ca = ca[0] if ca else {}
         rec["lower_s"] = round(t_lower, 2)
         rec["compile_s"] = round(t_compile, 2)
         rec["memory"] = {

@@ -26,13 +26,9 @@ CLIENT_AXIS = "client"
 
 
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; older jax only knows Auto
-    # axes, which is exactly what we want — so omit the kwarg there.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -54,13 +50,11 @@ def make_client_mesh(num_shards: int):
             f"client mesh wants {num_shards} devices but only "
             f"{len(devices)} are available"
         )
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.sharding.Mesh(
-            np.array(devices[:num_shards]),
-            (CLIENT_AXIS,),
-            axis_types=(jax.sharding.AxisType.Auto,),
-        )
-    return jax.sharding.Mesh(np.array(devices[:num_shards]), (CLIENT_AXIS,))
+    return jax.sharding.Mesh(
+        np.array(devices[:num_shards]),
+        (CLIENT_AXIS,),
+        axis_types=(jax.sharding.AxisType.Auto,),
+    )
 
 
 def make_test_mesh(data: int = 2, model: int = 2, pod: int = 0, client: int = 0):

@@ -36,6 +36,7 @@ from repro.core.reputation import init_reputation
 from repro.data import make_token_stream
 from repro.fed.distributed import FedRoundConfig, make_fed_round
 from repro.models import build_model
+from repro.utils.compile_cache import use_compile_cache
 
 
 def make_fed_batches(cfg, stream, rng, *, K, S, b, seq):
@@ -125,6 +126,7 @@ def main(argv=None):
                          "amplified inputs (paper-style strong faults)")
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.workload == "lora":
         return run_lora(args)

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis", reason="hypothesis not installed (pip install .[test])")
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core import (
     afa_aggregate,
@@ -48,6 +48,8 @@ def test_afa_permutation_equivariant(seed, K, d):
 
 @settings(max_examples=20, deadline=None)
 @given(seed=st.integers(0, 2**31 - 1), K=st.integers(3, 12), d=st.integers(4, 128))
+# similarities one f32 rounding apart: a tie, not an outlier (SIM_TIE_RTOL)
+@example(seed=14336, K=9, d=93)
 def test_afa_identical_updates_fixed_point(seed, K, d):
     """If every client sends the same w, the aggregate IS w and all keep."""
     r = np.random.default_rng(seed)

@@ -22,8 +22,9 @@ try:  # the hypothesis property is extra depth; the rest must run regardless
 except ImportError:
     HAVE_HYPOTHESIS = False
 
-from repro.core.afa import AFAConfig, afa_aggregate
+from repro.core.afa import SIM_TIE_RTOL, AFAConfig, afa_aggregate
 from repro.kernels import afa_screen
+from repro.kernels.afa_screen import SIM_TIE_RTOL as KERNEL_SIM_TIE_RTOL
 
 RNG = np.random.default_rng(7)
 
@@ -132,6 +133,22 @@ def test_fused_kernel_ddof_and_thresholds():
     cfg = AFAConfig(variant="gram", xi0=1.0, delta_xi=0.25, max_rounds=6, ddof=1)
     ref = _assert_matches_reference(u, n_k, p_k, None, cfg, bitwise=True)
     assert int(ref.rounds) >= 1  # the planted outliers force screening work
+
+
+@pytest.mark.parametrize("variant", ["gram", "iterative"])
+@pytest.mark.parametrize("mode", ["interpret", "jnp"])
+def test_rounding_ties_are_kept(variant, mode):
+    """Identical client rows give similarities a few f32 roundings apart;
+    every route treats them as ties (``SIM_TIE_RTOL``) and keeps them all."""
+    assert KERNEL_SIM_TIE_RTOL == SIM_TIE_RTOL  # the kernel mirrors core
+    r = np.random.default_rng(14336)
+    w = r.normal(size=(93,)).astype(np.float32)
+    u = jnp.asarray(np.tile(w, (9, 1)))
+    n_k = jnp.asarray(r.uniform(1, 50, 9).astype(np.float32))
+    p_k = jnp.asarray(r.uniform(0.2, 1.0, 9).astype(np.float32))
+    res = afa_aggregate(u, n_k, p_k, config=AFAConfig(variant=variant, use_kernels=mode))
+    assert np.asarray(res.good_mask).all()
+    np.testing.assert_allclose(np.asarray(res.aggregate), w, rtol=1e-5, atol=1e-6)
 
 
 # --------------------------- launch structure --------------------------------

@@ -111,6 +111,7 @@ def test_lying_declaration_is_an_error_on_every_route():
         d = x.shape[1]
         return pl.pallas_call(
             _lint_lying_kernel,
+            name="_lint_lying_kernel",
             grid=(4,),
             in_specs=[pl.BlockSpec((x.shape[0], d // 4), lambda b: (0, b))],
             out_specs=pl.BlockSpec((x.shape[0], x.shape[0]), lambda b: (0, 0)),
@@ -185,14 +186,14 @@ def test_launch_budget_violation_yields_error_finding():
 def test_callback_inside_scan_body_is_flagged():
     def bad(x):
         def body(c, _):
-            jax.debug.print("c={c}", c=c)  # traces to debug_callback
+            jax.debug.print("c={c}", c=c)  # traces to debug_print
             return c + 1.0, c
 
         return jax.lax.scan(body, x, None, length=4)
 
     findings = check_no_host_transfers(bad, jnp.float32(0.0))
     assert any(
-        f.severity == "error" and "debug_callback" in f.message
+        f.severity == "error" and "debug_print" in f.message
         for f in findings
     )
 
@@ -262,7 +263,6 @@ os.environ["XLA_FLAGS"] = (
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis.collectives import CollectiveBudget, check_screening_budget
@@ -294,8 +294,8 @@ def body(u, n_k, p_k, mask):
     return (r.aggregate, r.good_mask, r.rounds, r.similarities)
 
 spec = P(axis)
-sharded = shard_map(body, mesh=mesh, in_specs=(spec,) * 4,
-                    out_specs=(P(), spec, P(), spec), check_rep=False)
+sharded = jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 4,
+                        out_specs=(P(), spec, P(), spec), check_vma=False)
 tight = check_screening_budget(
     sharded, u, n_k, p_k, mask,
     budget=CollectiveBudget(max_heavy_psum=0, max_heavy_all_gather=0,

@@ -120,7 +120,17 @@ print("ONE_SHARD_BIT_IDENTICAL")
 # 4-way client mesh, segmented with per-shard compaction: numerically equal
 # trajectories (the (D,) psum re-associates one summation; every discrete
 # outcome — screening masks, blocking rounds — must match exactly)
+import jax
+segment_compiles = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda name, *_, fun_name=None, **__: segment_compiles.append(fun_name)
+    if name == "/jax/core/compile/backend_compile_duration"
+    and fun_name == "jit(segment_fn)" else None
+)
 four = run(4, seg=4)
+# one program per bucket layout (5 rows a shard, then 4): the first
+# segment's inputs sit on the mesh like every later segment's
+assert len(segment_compiles) == 2, segment_compiles
 np.testing.assert_allclose(
     np.asarray(ref.test_error), np.asarray(four.test_error),
     rtol=1e-4, atol=1e-4,
@@ -145,11 +155,6 @@ from jax.sharding import PartitionSpec as P
 from repro.analysis import collective_uses
 from repro.attacks import apply_update_attack
 from repro.launch.mesh import client_axis, make_client_mesh
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map
 
 K = 16
 rng = np.random.default_rng(3)
@@ -177,10 +182,10 @@ for scenario in ("alie", "ipm"):
             scenario, props, prev, bad_rows, benign_rows, key, axis_name=axis
         )
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         attacked, mesh=mesh,
         in_specs=(row, rep, P(axis), P(axis)), out_specs=row,
-        check_rep=False,
+        check_vma=False,
     )
     got = sharded(proposals, w_prev, bad, benign)
     for a, b in zip(

@@ -2,6 +2,7 @@
 the pure server core, the vmapped seed sweep, and the padded shard stacking
 the device-side batch draw depends on."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -15,6 +16,7 @@ from repro.fed import (
     run_simulation,
     run_sweep,
 )
+from repro.fed.simulator import first_segment
 
 
 @pytest.fixture(scope="module")
@@ -52,6 +54,12 @@ def test_fused_scan_bit_equivalent_to_eager_rounds(eq_data, scenario):
     for gf, ge in zip(fused.good_mask_history, eager.good_mask_history):
         np.testing.assert_array_equal(np.asarray(gf), np.asarray(ge))
     np.testing.assert_array_equal(fused.blocked_round, eager.blocked_round)
+    np.testing.assert_array_equal(
+        np.stack(fused.similarity_history), np.stack(eager.similarity_history)
+    )
+    for lf, le in zip(jax.tree_util.tree_leaves(fused.params),
+                      jax.tree_util.tree_leaves(eager.params)):
+        np.testing.assert_array_equal(np.asarray(lf), np.asarray(le))
 
 
 def test_fused_engine_trains(eq_data):
@@ -95,10 +103,19 @@ def _seg_sim(scenario, rounds=12, **kw):
 
 def _assert_same_trajectory(a, b):
     np.testing.assert_array_equal(np.asarray(a.test_error), np.asarray(b.test_error))
-    np.testing.assert_array_equal(
-        np.stack(a.good_mask_history), np.stack(b.good_mask_history)
-    )
+    good = np.stack(a.good_mask_history)
+    np.testing.assert_array_equal(good, np.stack(b.good_mask_history))
     np.testing.assert_array_equal(a.blocked_round, b.blocked_round)
+    # compaction drops blocked clients' rows, so their similarities are not
+    # computed there: compare the clients the rule kept.  The similarity
+    # dots then run over fewer rows, which may move one f32 rounding
+    np.testing.assert_allclose(
+        np.stack(a.similarity_history)[good], np.stack(b.similarity_history)[good],
+        rtol=2e-7, atol=0,
+    )
+    for la, lb in zip(jax.tree_util.tree_leaves(a.params),
+                      jax.tree_util.tree_leaves(b.params)):
+        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
 
 
 def test_segmented_compacted_bit_equals_one_shot_fused(eq_data):
@@ -135,6 +152,50 @@ def test_segmented_ragged_last_segment(eq_data):
         eq_data, _seg_sim("byzantine", rounds=11, segment_rounds=5), cfg
     )
     _assert_same_trajectory(base, seg)
+
+
+@pytest.mark.parametrize("rule", ["afa", "fa"])
+def test_result_carries_similarities_and_final_params(eq_data, rule):
+    """A fused run reports, each round, AFA's final-iteration similarities
+    (kept clients score above the byzantine ones it dropped; zeros for a
+    rule without them) and its final global parameters."""
+    sim = _seg_sim("byzantine", rounds=4, segment_rounds=2, compact=True)
+    res = run_simulation(eq_data, sim, ServerConfig(rule=rule, num_clients=10))
+    sims = np.stack(res.similarity_history)
+    assert sims.shape == (4, 10)
+    if rule == "afa":
+        good0, bad = res.good_mask_history[0], res.bad_clients
+        assert not good0[bad].any()
+        assert sims[0][good0].min() > sims[0][bad].max()
+    else:
+        assert not sims.any()
+    init = jax.tree_util.tree_leaves(res.params)
+    assert init and all(np.isfinite(np.asarray(leaf)).all() for leaf in init)
+
+
+def test_first_segment_is_the_runs_first_program(eq_data):
+    """``first_segment`` hands out the run's first segment call: compiled
+    ahead of time, the call compiles nothing more, and its rounds are the
+    run's first ``segment_rounds`` rounds."""
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, *_, **__: compiles.append(name)
+        if name == "/jax/core/compile/backend_compile_duration" else None
+    )
+    cfg = ServerConfig(rule="afa", num_clients=10)
+    sim = _seg_sim("byzantine", segment_rounds=4, compact=True)
+    seg_fn, args = first_segment(eq_data, sim, cfg)
+    seg_fn.lower(*args).compile()
+    n_compiled = len(compiles)
+    _, _, traj = seg_fn(*args)
+    assert len(compiles) == n_compiled
+    run = run_simulation(eq_data, sim, cfg)
+    np.testing.assert_array_equal(
+        np.asarray(traj.test_error, np.float64) * 100.0, run.test_error[:4]
+    )
+    np.testing.assert_array_equal(
+        np.asarray(traj.good_mask), np.stack(run.good_mask_history[:4])
+    )
 
 
 # ------------------------------ seed sweep -----------------------------------

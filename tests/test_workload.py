@@ -60,12 +60,14 @@ from repro.utils.trees import (
 # reference geometry — small enough that every (rule, scenario) case compiles
 # and runs in a couple of seconds on CPU
 K, N, DIM, OUT = 5, 20, 10, 3
-ROUNDS, BATCH_S, BATCH_B = 6, 2, 4
+ROUNDS, BATCH_S, BATCH_B = 8, 2, 4
 SIZES = (DIM, 6, OUT)
 SEED = 7
-# Beta(1,1) start: four bad rounds push betainc(1, 5, 0.5) past 0.95, so
-# blocking FIRES inside the 6-round window and the bit-identity property
-# covers the blocked regime, not just the screening one
+# Beta(1,1) start: at this seed AFA keeps every client in round 0 and
+# screens byzantine client 0 out from round 1 on; six bad rounds after the
+# good one push betainc(2, 7, 0.5) past 0.95, so blocking FIRES inside the
+# 8-round window and the bit-identity property covers the blocked regime,
+# not just the screening one
 ALPHA0 = BETA0 = 1.0
 
 
