@@ -42,6 +42,7 @@ from typing import Any, Iterable, Optional, Union
 from repro.fed.server import ServerConfig
 from repro.fed.simulator import SimConfig, SimResult, SweepResult, simulate, sweep
 from repro.fed.workload import ClientWorkload, DnnWorkload, get_workload, simulate_llm
+from repro.utils.spans import span
 
 WorkloadLike = Union[None, str, ClientWorkload]
 
@@ -102,38 +103,41 @@ def run(
         server = ServerConfig(num_clients=sim.num_clients)
 
     classification = workload is None or isinstance(workload, DnnWorkload)
-    if classification:
-        if extra:
-            raise TypeError(
-                f"unexpected keyword arguments for the classification "
-                f"route: {sorted(extra)}"
-            )
-        if data is None:
-            raise ValueError(
-                "the classification route needs `data` (a "
-                "SyntheticClassification); build one with repro.data"
-            )
-        if seeds is not None:
-            return sweep(data, sim, server, seeds)
-        return simulate(data, sim, server, eval_every=eval_every, workload=workload)
+    route = ("simulate" if seeds is None else "sweep") if classification else "llm"
+    with span("fed.run", route=route, engine=sim.engine, K=sim.num_clients,
+              rounds=sim.rounds):
+        if classification:
+            if extra:
+                raise TypeError(
+                    f"unexpected keyword arguments for the classification "
+                    f"route: {sorted(extra)}"
+                )
+            if data is None:
+                raise ValueError(
+                    "the classification route needs `data` (a "
+                    "SyntheticClassification); build one with repro.data"
+                )
+            if seeds is not None:
+                return sweep(data, sim, server, seeds)
+            return simulate(data, sim, server, eval_every=eval_every, workload=workload)
 
-    # LLM / delta-workload route: the fused driver owns its geometry knobs
-    if seeds is not None:
-        raise ValueError(
-            "seed sweeps are not wired for the LLM route; loop over "
-            "sim.seed instead"
+        # LLM / delta-workload route: the fused driver owns its geometry knobs
+        if seeds is not None:
+            raise ValueError(
+                "seed sweeps are not wired for the LLM route; loop over "
+                "sim.seed instead"
+            )
+        llm_kwargs = dict(
+            clients=sim.num_clients,
+            byzantine=int(round(sim.bad_frac * sim.num_clients)),
+            rounds=sim.rounds,
+            local_steps=sim.local_epochs,
+            batch=sim.batch_size,
+            seed=sim.seed,
+            lr=sim.lr,
+            scenario=sim.scenario,
+            rule=server.rule,
+            data=data,
         )
-    llm_kwargs = dict(
-        clients=sim.num_clients,
-        byzantine=int(round(sim.bad_frac * sim.num_clients)),
-        rounds=sim.rounds,
-        local_steps=sim.local_epochs,
-        batch=sim.batch_size,
-        seed=sim.seed,
-        lr=sim.lr,
-        scenario=sim.scenario,
-        rule=server.rule,
-        data=data,
-    )
-    llm_kwargs.update(extra)  # samples_per_client / seq / n_test / overrides
-    return simulate_llm(workload, **llm_kwargs)
+        llm_kwargs.update(extra)  # samples_per_client / seq / n_test / overrides
+        return simulate_llm(workload, **llm_kwargs)

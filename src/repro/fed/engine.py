@@ -67,6 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.attacks import UPDATE_ATTACK_SCENARIOS, apply_update_attack
+from repro.utils.spans import span
 from repro.utils.trees import tree_broadcast_clients, tree_select_rows
 
 # scenarios whose proposal transform touches only its own client row — these
@@ -150,19 +151,21 @@ def _train_and_attack(
     def train_one(cbatch, ckey):
         return workload.local_update(cfg, params, cbatch, ckey)
 
-    proposals = jax.vmap(train_one)(batch, keys)
-    # non-trainers hold w_t until the attack layer overwrites their row
-    proposals = tree_select_rows(
-        train_mask, proposals, tree_broadcast_clients(w_prev, K)
-    )
-    return apply_update_attack(
-        cfg.scenario, proposals, w_prev, bad_mask, benign_mask, akey,
-        byzantine_scale=cfg.byzantine_scale,
-        z_max=cfg.alie_z_max,
-        eps=cfg.ipm_eps,
-        client_ids=client_ids,
-        axis_name=client_axis,
-    )
+    with jax.named_scope("local_update"):
+        proposals = jax.vmap(train_one)(batch, keys)
+        # non-trainers hold w_t until the attack layer overwrites their row
+        proposals = tree_select_rows(
+            train_mask, proposals, tree_broadcast_clients(w_prev, K)
+        )
+    with jax.named_scope("attack"):
+        return apply_update_attack(
+            cfg.scenario, proposals, w_prev, bad_mask, benign_mask, akey,
+            byzantine_scale=cfg.byzantine_scale,
+            z_max=cfg.alie_z_max,
+            eps=cfg.ipm_eps,
+            client_ids=client_ids,
+            axis_name=client_axis,
+        )
 
 
 @functools.lru_cache(maxsize=64)
@@ -250,12 +253,13 @@ def _propose_round(
 
     # device-side minibatch draw: one key per (round, client), per-client
     # maxval — pad rows carry length 1 so the draw range is never empty
-    bbase = jax.random.fold_in(base, _BATCH_STREAM)
-    bkeys = jax.vmap(lambda o: jax.random.fold_in(bbase, o))(offsets)
-    idx = jax.vmap(
-        lambda k, n: jax.random.randint(k, (batch_s, batch_b), 0, n)
-    )(bkeys, data.lengths)
-    batch = {"x": _gather_rows(data.x, idx), "y": _gather_rows(data.y, idx)}
+    with jax.named_scope("local_update"):
+        bbase = jax.random.fold_in(base, _BATCH_STREAM)
+        bkeys = jax.vmap(lambda o: jax.random.fold_in(bbase, o))(offsets)
+        idx = jax.vmap(
+            lambda k, n: jax.random.randint(k, (batch_s, batch_b), 0, n)
+        )(bkeys, data.lengths)
+        batch = {"x": _gather_rows(data.x, idx), "y": _gather_rows(data.y, idx)}
     proposals = _train_and_attack(
         workload, cfg, params, batch,
         client_keys_traced(seed, rnd, ids, num_clients_total),
@@ -339,28 +343,35 @@ def _round_body(
         # row template: one client's proposal layout (= params for full-param
         # workloads, the adapter tree for delta workloads)
         pspec = workload.delta_spec(params)
-        state, res = server_step(
-            state, pack_stack(proposals, pspec), data.n_k, mask0,
-            rule=rule, opts=opts, delta_block=delta_block, layout="packed",
-        )
-        aggregate = unpack_stack(res.aggregate, pspec)
+        with jax.named_scope("pack"):
+            packed = pack_stack(proposals, pspec)
+        with jax.named_scope("server_step"):
+            state, res = server_step(
+                state, packed, data.n_k, mask0,
+                rule=rule, opts=opts, delta_block=delta_block, layout="packed",
+            )
+        with jax.named_scope("apply"):
+            aggregate = unpack_stack(res.aggregate, pspec)
     else:
-        state, res = server_step(
-            state, proposals, data.n_k, mask0,
-            rule=rule, opts=opts, delta_block=delta_block, layout=agg_layout,
-        )
+        with jax.named_scope("server_step"):
+            state, res = server_step(
+                state, proposals, data.n_k, mask0,
+                rule=rule, opts=opts, delta_block=delta_block, layout=agg_layout,
+            )
         aggregate = res.aggregate
     # empty-participation guard: a zero update keeps the previous proposal
     # point (identity, bit for bit, whenever any client is live); the guard
     # runs in proposal space so delta workloads never where-select the
     # frozen base
-    w_prev = workload.codec.proposal_of(params)
-    aggregate = jax.tree_util.tree_map(
-        lambda prev, new: jnp.where(res.all_blocked, prev, new),
-        w_prev, aggregate,
-    )
-    params = workload.codec.apply(params, aggregate)
-    err = workload.eval_metric(params, data.x_test, data.y_test)
+    with jax.named_scope("apply"):
+        w_prev = workload.codec.proposal_of(params)
+        aggregate = jax.tree_util.tree_map(
+            lambda prev, new: jnp.where(res.all_blocked, prev, new),
+            w_prev, aggregate,
+        )
+        params = workload.codec.apply(params, aggregate)
+    with jax.named_scope("eval"):
+        err = workload.eval_metric(params, data.x_test, data.y_test)
     sims = getattr(res, "similarities", None)
     if sims is None:
         sims = jnp.zeros(res.good_mask.shape, jnp.float32)
@@ -697,9 +708,10 @@ def _make_fused_segment_cached(
         @jax.jit
         def segment_fn(params, state, seed, data: FusedData, bad, client_ids,
                        seg_start):
-            (params, state), traj = _scan(
-                params, state, seed, data, bad, client_ids, seg_start
-            )
+            with _trace_span(data, seg_len):
+                (params, state), traj = _scan(
+                    params, state, seed, data, bad, client_ids, seg_start
+                )
             return params, state, traj
 
         return segment_fn
@@ -727,12 +739,20 @@ def _make_fused_segment_cached(
     @jax.jit
     def segment_fn(params, state, seed, data: FusedData, bad, client_ids,
                    seg_start):
-        return sharded(
-            params, state, jnp.asarray(seed, jnp.uint32), data, bad,
-            client_ids, jnp.asarray(seg_start, jnp.int32),
-        )
+        with _trace_span(data, seg_len):
+            return sharded(
+                params, state, jnp.asarray(seed, jnp.uint32), data, bad,
+                client_ids, jnp.asarray(seg_start, jnp.int32),
+            )
 
     return segment_fn
+
+
+def _trace_span(data: FusedData, seg_len: int):
+    """The ``fed.segment.trace`` span around a segment's Python body, which
+    runs only while JAX traces it: each record is one (re)trace."""
+    return span("fed.segment.trace", bucket=int(data.x.shape[0]),
+                seg_len=int(seg_len))
 
 
 def sweep_fused_sim(scan_fn, workload, seeds, data: FusedData):

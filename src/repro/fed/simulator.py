@@ -96,6 +96,7 @@ from repro.fed.server import (
     scatter_server_state,
 )
 from repro.fed.workload import DnnWorkload
+from repro.utils.spans import span
 from repro.utils.trees import tree_stack
 
 
@@ -298,7 +299,9 @@ def simulate(
 ) -> SimResult:
     """The classification-simulator implementation behind
     ``repro.fed.api.run`` — route ``sim.engine`` to its round engine."""
-    setup = _Setup(data, sim, workload=workload)
+    with span("fed.setup") as attrs:
+        setup = _Setup(data, sim, workload=workload)
+        attrs["h2d_bytes"] = int(setup.x_test.nbytes + setup.y_test.nbytes)
     if sim.client_shards > 0 and sim.engine != "fused":
         raise ValueError(
             f"client_shards requires engine='fused' (got {sim.engine!r})"
@@ -759,57 +762,70 @@ def _run_fused_segmented(
     while seg_start < T:
         t0 = time.perf_counter()
         seg_len = min(S, T - seg_start)
-        if sim.compact:
-            blocked_c = np.asarray(state_c.reputation.blocked)[: len(kept)]
-            # pad slots (kept == -1, sharded layout) are blocked and drop out
-            live = kept[~blocked_c & (kept >= 0)]
-        else:
-            live = np.arange(K)
-        new_kept, new_bucket = _segment_layout(live, K, n_shards, mesh)
-        if bucket != new_bucket:
-            # bucket boundary crossed: preserve the rows being dropped, then
-            # compact to the smaller layout (the first iteration lands here
-            # too, with the identity map at bucket = K and nothing to save)
-            if bucket is not None:
-                state_full = scatter_server_state(state_full, state_c, kept)
-            bucket, kept = new_bucket, new_kept
-            params, state_c, data_c, bad_c, ids_c = _segment_inputs(
-                setup, params, state_full, kept, bucket, mesh
-            )
-        seg_fn = _segment_fn(
-            setup, server_cfg, seg_len, mesh,
-            None if mesh is None else bucket // n_shards,
-        )
-        params, state_c, traj = seg_fn(
-            params, state_c, seed, data_c, bad_c, ids_c, jnp.int32(seg_start)
-        )
-        jax.block_until_ready(traj)
+        with span("fed.segment", seg_start=seg_start, seg_len=seg_len) as seg:
+            with span("fed.segment.layout"):
+                if sim.compact:
+                    blocked_c = np.asarray(state_c.reputation.blocked)[: len(kept)]
+                    # pad slots (kept == -1, sharded layout) are blocked and drop out
+                    live = kept[~blocked_c & (kept >= 0)]
+                else:
+                    live = np.arange(K)
+                new_kept, new_bucket = _segment_layout(live, K, n_shards, mesh)
+            if bucket != new_bucket:
+                # bucket boundary crossed: preserve the rows being dropped, then
+                # compact to the smaller layout (the first iteration lands here
+                # too, with the identity map at bucket = K and nothing to save)
+                with span("fed.segment.stage", bucket=int(new_bucket)) as stage:
+                    if bucket is not None:
+                        state_full = scatter_server_state(state_full, state_c, kept)
+                    bucket, kept = new_bucket, new_kept
+                    params, state_c, data_c, bad_c, ids_c = _segment_inputs(
+                        setup, params, state_full, kept, bucket, mesh
+                    )
+                    stage["rows"] = int((kept >= 0).sum())
+                    # the compacted stacks and masks (_Setup placed the test set)
+                    stage["h2d_bytes"] = int(
+                        sum(a.nbytes for a in (*data_c[:4], bad_c, ids_c)))
+            seg.update(bucket=int(bucket), live=len(live))
+            with span("fed.segment.call"):
+                seg_fn = _segment_fn(
+                    setup, server_cfg, seg_len, mesh,
+                    None if mesh is None else bucket // n_shards,
+                )
+                params, state_c, traj = seg_fn(
+                    params, state_c, seed, data_c, bad_c, ids_c,
+                    jnp.int32(seg_start),
+                )
+            with span("fed.segment.wait"):
+                jax.block_until_ready(traj)
 
-        # stitch the (seg_len, bucket) segment outputs into full-K rows via
-        # the index map; dropped clients keep the default good_mask = False
-        # (they are blocked, exactly what the one-shot scan emits for them)
-        end = seg_start + seg_len
-        valid = kept >= 0
-        test_error[seg_start:end] = np.asarray(traj.test_error, np.float64)
-        good[seg_start:end, kept[valid]] = (
-            np.asarray(traj.good_mask)[:, np.nonzero(valid)[0]]
-        )
-        sims[seg_start:end, kept[valid]] = (
-            np.asarray(traj.similarities)[:, np.nonzero(valid)[0]]
-        )
+            # stitch the (seg_len, bucket) segment outputs into full-K rows via
+            # the index map; dropped clients keep the default good_mask = False
+            # (they are blocked, exactly what the one-shot scan emits for them)
+            with span("fed.segment.stitch"):
+                end = seg_start + seg_len
+                valid = kept >= 0
+                test_error[seg_start:end] = np.asarray(traj.test_error, np.float64)
+                good[seg_start:end, kept[valid]] = (
+                    np.asarray(traj.good_mask)[:, np.nonzero(valid)[0]]
+                )
+                sims[seg_start:end, kept[valid]] = (
+                    np.asarray(traj.similarities)[:, np.nonzero(valid)[0]]
+                )
         round_times[seg_start:end] = (time.perf_counter() - t0) / seg_len
         seg_start = end
 
-    state_full = scatter_server_state(state_full, state_c, kept)
-    errs = test_error * 100.0
-    test_error_list = [
-        float(errs[r]) for r in range(T) if r % eval_every == 0 or r == T - 1
-    ]
-    good_hist = [gm for gm in good]
-    return setup.result(
-        np.asarray(state_full.rounds_blocked), test_error_list, good_hist,
-        0.0, 0.0, list(round_times), params, list(sims),
-    )
+    with span("fed.result"):
+        state_full = scatter_server_state(state_full, state_c, kept)
+        errs = test_error * 100.0
+        test_error_list = [
+            float(errs[r]) for r in range(T) if r % eval_every == 0 or r == T - 1
+        ]
+        good_hist = [gm for gm in good]
+        return setup.result(
+            np.asarray(state_full.rounds_blocked), test_error_list, good_hist,
+            0.0, 0.0, list(round_times), params, list(sims),
+        )
 
 
 @dataclasses.dataclass

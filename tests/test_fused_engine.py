@@ -2,6 +2,8 @@
 the pure server core, the vmapped seed sweep, and the padded shard stacking
 the device-side batch draw depends on."""
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -196,6 +198,38 @@ def test_first_segment_is_the_runs_first_program(eq_data):
     np.testing.assert_array_equal(
         np.asarray(traj.good_mask), np.stack(run.good_mask_history[:4])
     )
+
+
+@pytest.mark.parametrize("segment_rounds", [0, 4])
+def test_phase_scopes_leave_the_trajectory_bit_identical(
+    eq_data, monkeypatch, segment_rounds
+):
+    """The round body's ``named_scope`` phases change op metadata only: the
+    scoped program and the same program built with every scope a no-op give
+    the same trajectory bit for bit (one-shot and segmented, compacting)."""
+    from repro.fed import engine
+
+    cfg = ServerConfig(rule="afa", num_clients=10)
+    sim = _seg_sim("byzantine", segment_rounds=segment_rounds, compact=True)
+    scoped = run_simulation(eq_data, sim, cfg)
+    caches = (engine._make_fused_sim_cached, engine._make_fused_segment_cached)
+    for cache in caches:
+        cache.cache_clear()
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    try:
+        unscoped = run_simulation(eq_data, sim, cfg)
+    finally:
+        for cache in caches:
+            cache.cache_clear()
+    np.testing.assert_array_equal(scoped.test_error, unscoped.test_error)
+    np.testing.assert_array_equal(np.stack(scoped.good_mask_history),
+                                  np.stack(unscoped.good_mask_history))
+    np.testing.assert_array_equal(scoped.blocked_round, unscoped.blocked_round)
+    np.testing.assert_array_equal(np.stack(scoped.similarity_history),
+                                  np.stack(unscoped.similarity_history))
+    for a, b in zip(jax.tree_util.tree_leaves(scoped.params),
+                    jax.tree_util.tree_leaves(unscoped.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ------------------------------ seed sweep -----------------------------------
