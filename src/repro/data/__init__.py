@@ -7,7 +7,9 @@ from repro.data.synthetic import (
 )
 from repro.data.sharding import (
     compact_stack,
+    dirichlet_shard_indices,
     dirichlet_shards,
+    iid_shard_indices,
     iid_shards,
     padded_stack,
     pow2_bucket,
@@ -21,7 +23,9 @@ __all__ = [
     "make_spambase_like",
     "make_token_stream",
     "iid_shards",
+    "iid_shard_indices",
     "dirichlet_shards",
+    "dirichlet_shard_indices",
     "padded_stack",
     "compact_stack",
     "pow2_bucket",

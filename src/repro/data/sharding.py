@@ -1,25 +1,32 @@
 """Client shard construction: IID (the paper splits training data equally
 across clients) and Dirichlet non-IID (standard fed-learning benchmark),
-plus the padded ``(K, n_max, ...)`` stacking the fused round engine samples
-minibatches from on device and its inverse, ``compact_stack``, which the
-segmented fused engine uses to drop blocked clients between scan segments
-(DESIGN.md §2)."""
+each as per-client row indices and as shard copies, plus the padded
+``(K, n_max, ...)`` stacking the fused round engine samples minibatches from
+on device and its inverse, ``compact_stack``, which drops blocked clients
+from it.  The fused engines stage the same stacks on the device by a row
+index map into a resident pool (``fed/simulator._compact_inputs``,
+DESIGN.md §2); these host forms are its reference and serve the workloads
+that stack their own shards."""
 
 from __future__ import annotations
 
 import numpy as np
 
 
+def iid_shard_indices(n: int, num_clients: int, seed: int = 0) -> list:
+    """Each client's row indices into the ``n`` training rows under the equal
+    random split of :func:`iid_shards`."""
+    rng = np.random.default_rng(seed)
+    return np.array_split(rng.permutation(n), num_clients)
+
+
 def iid_shards(x: np.ndarray, y: np.ndarray, num_clients: int, seed: int = 0):
     """Equal random split — the paper's setting ("we split the training data
     equally across all clients")."""
-    rng = np.random.default_rng(seed)
-    idx = rng.permutation(len(x))
-    parts = np.array_split(idx, num_clients)
-    return [(x[p], y[p]) for p in parts]
+    return [(x[p], y[p]) for p in iid_shard_indices(len(x), num_clients, seed)]
 
 
-def _stack_dtype(a: np.ndarray):
+def stack_dtype(a: np.ndarray):
     """Device dtype of a stacked shard: integer features (e.g. token ids)
     stay int32, everything else is cast to float32 (the classification
     path's historical behaviour)."""
@@ -43,7 +50,7 @@ def padded_stack(shards):
     n_max = max(len(x) for x, _ in shards)
     x0 = np.asarray(shards[0][0])
     y0 = np.asarray(shards[0][1])
-    x_pad = np.zeros((K, n_max) + x0.shape[1:], _stack_dtype(x0))
+    x_pad = np.zeros((K, n_max) + x0.shape[1:], stack_dtype(x0))
     y_pad = np.zeros((K, n_max) + y0.shape[1:], np.int32)
     lengths = np.zeros((K,), np.int32)
     for k, (x, y) in enumerate(shards):
@@ -144,12 +151,11 @@ def pow2_bucket(n_live: int, cap: int) -> int:
     return max(1, min(b, cap))
 
 
-def dirichlet_shards(
-    x: np.ndarray, y: np.ndarray, num_clients: int, alpha: float = 0.5, seed: int = 0
-):
-    """Label-skewed split: per-class Dirichlet(alpha) allocation over clients.
-    Smaller alpha -> more heterogeneous shards (and *unequal* n_k, exercising
-    AFA's n_k-weighted aggregation where MKRUM/COMED ignore it)."""
+def dirichlet_shard_indices(
+    y: np.ndarray, num_clients: int, alpha: float = 0.5, seed: int = 0
+) -> list:
+    """Each client's row indices into ``y``'s rows under the label-skewed
+    split of :func:`dirichlet_shards`."""
     rng = np.random.default_rng(seed)
     classes = np.unique(y)
     buckets: list[list[int]] = [[] for _ in range(num_clients)]
@@ -162,7 +168,19 @@ def dirichlet_shards(
             b.extend(part.tolist())
     out = []
     for b in buckets:
-        b = np.asarray(b if b else [int(rng.integers(0, len(x)))])
+        b = np.asarray(b if b else [int(rng.integers(0, len(y)))])
         rng.shuffle(b)
-        out.append((x[b], y[b]))
+        out.append(b)
     return out
+
+
+def dirichlet_shards(
+    x: np.ndarray, y: np.ndarray, num_clients: int, alpha: float = 0.5, seed: int = 0
+):
+    """Label-skewed split: per-class Dirichlet(alpha) allocation over clients.
+    Smaller alpha -> more heterogeneous shards (and *unequal* n_k, exercising
+    AFA's n_k-weighted aggregation where MKRUM/COMED ignore it)."""
+    return [
+        (x[b], y[b])
+        for b in dirichlet_shard_indices(y, num_clients, alpha=alpha, seed=seed)
+    ]

@@ -52,8 +52,10 @@ train honestly on it.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 import warnings
+import weakref
 from typing import NamedTuple
 
 import jax
@@ -68,12 +70,12 @@ from repro.attacks import (
 )
 from repro.data import (
     SyntheticClassification,
-    compact_stack,
-    iid_shards,
-    padded_stack,
+    dirichlet_shard_indices,
+    iid_shard_indices,
     pow2_bucket,
     shard_compact_plan,
 )
+from repro.data.sharding import stack_dtype
 from repro.fed.engine import (
     EngineConfig,
     FusedData,
@@ -166,22 +168,27 @@ class _Setup:
         self.bad_mask[self.bad] = True
 
         if sim.sharding == "dirichlet":
-            from repro.data import dirichlet_shards
-
-            shards = dirichlet_shards(
-                data.x_train, data.y_train, K, alpha=sim.dirichlet_alpha, seed=sim.seed
+            self.shard_rows = dirichlet_shard_indices(
+                data.y_train, K, alpha=sim.dirichlet_alpha, seed=sim.seed
             )
         else:
-            shards = iid_shards(data.x_train, data.y_train, K, seed=sim.seed)
+            self.shard_rows = iid_shard_indices(len(data.x_train), K, seed=sim.seed)
+        self.data = data
         binary = data.num_classes == 2
         # data-level poisoning
         self.poisoned = []
-        for k, (x, y) in enumerate(shards):
+        rewritten = False
+        for k, rows in enumerate(self.shard_rows):
+            shard = x, y = data.x_train[rows], data.y_train[rows]
             if self.bad_mask[k] and sim.scenario == "flipping":
                 x, y = flip_labels(x, y)
             elif self.bad_mask[k] and sim.scenario == "noisy":
                 x, y = noisy_features(x, y, self.rng, binary=binary)
+            rewritten |= x is not shard[0] or y is not shard[1]
             self.poisoned.append((x, y))
+        # shards that are rows of ``data`` untouched: the fused engines then
+        # stage them from a device-resident copy of the dataset (_pool)
+        self.rows_of_data = not rewritten
 
         out_units = 1 if binary else data.num_classes
         self.sizes = (data.dim, *sim.hidden, out_units)
@@ -459,24 +466,117 @@ def _run_looped(setup: _Setup, server_cfg: ServerConfig, eval_every: int) -> Sim
 # ---------------------------------------------------------------------------
 
 
-def _padded(setup: _Setup):
-    """Host-side padded stacks, cached on the setup (the segmented engine
-    re-gathers from them at every compaction)."""
-    if not hasattr(setup, "_padded_stack"):
-        setup._padded_stack = padded_stack(setup.poisoned)
-    return setup._padded_stack
+class _Pool(NamedTuple):
+    """The device rows an experiment's client stacks are gathered from."""
+
+    x: jax.Array          # (N, *feat) in the stack dtype
+    y: jax.Array          # (N, *lab) int32
+    rows: np.ndarray      # (K, n_max) int32: client k's pool rows, -1 past n_k
+    lengths: np.ndarray   # (K,) int32 shard lengths
 
 
-def _fused_data(setup: _Setup) -> FusedData:
-    x_pad, y_pad, lengths = _padded(setup)
-    return FusedData(
-        x=jnp.asarray(x_pad),
-        y=jnp.asarray(y_pad),
-        lengths=jnp.asarray(lengths),
-        n_k=jnp.asarray(setup.n_k),
-        x_test=setup.x_test,
-        y_test=setup.y_test,
-    )
+# the device copy of the dataset last staged from, while that dataset lives:
+# (weak refs to its x_train and y_train, the client mesh or None, (x, y) on
+# the device)
+_dataset_pool: list = [None]
+
+
+def _forget_dataset_pool(ref) -> None:
+    entry = _dataset_pool[0]
+    if entry is not None and (ref is entry[0] or ref is entry[1]):
+        _dataset_pool[0] = None
+
+
+def _upload(x, y, mesh):
+    """``(x, y)`` on the device in the dtypes ``padded_stack`` gives, and
+    the bytes sent (every replica counts).  The device arrays are copies
+    that own their memory: a CPU client may alias a host array it is handed,
+    which would keep a cached dataset alive for as long as its pool."""
+    arrays = _jit_on(_copies, mesh, split=False)(
+        np.asarray(x, stack_dtype(x)), np.asarray(y, np.int32))
+    sent = sum(s.data.nbytes for a in arrays for s in a.addressable_shards)
+    return arrays, sent
+
+
+def _copies(x, y):
+    return jnp.copy(x), jnp.copy(y)
+
+
+@functools.lru_cache(maxsize=None)
+def _jit_on(fn, mesh, *, split: bool):
+    """``fn`` jitted.  Client-sharded, its two outputs land on the client
+    mesh: split over the client axis (``split``) or replicated."""
+    if mesh is None:
+        return jax.jit(fn)
+    from repro.launch.mesh import client_axis
+
+    spec = jax.sharding.PartitionSpec(client_axis(mesh) if split else None)
+    out = jax.sharding.NamedSharding(mesh, spec)
+    return jax.jit(fn, out_shardings=(out, out))
+
+
+def _row_map(row_lists) -> np.ndarray:
+    out = np.full((len(row_lists), max(len(r) for r in row_lists)), -1, np.int32)
+    for k, r in enumerate(row_lists):
+        out[k, : len(r)] = r
+    return out
+
+
+def _pool(setup: _Setup, mesh) -> tuple[_Pool, int]:
+    """The pool this experiment stages from, and the bytes uploaded for it
+    now (0 where it was already on the device).
+
+    Shards that are rows of the dataset (``setup.rows_of_data``) are
+    gathered from the dataset's own training rows, kept on the device in a
+    single-entry cache keyed by the identity of ``x_train`` and
+    ``y_train``: the next experiment on the same dataset finds them there.
+    The entry holds weak references and goes with the dataset.  Shards a
+    poisoning scenario rewrote are concatenated into this experiment's own
+    pool, uploaded once.  Client-sharded, a pool is replicated over the
+    client mesh."""
+    own = getattr(setup, "_own_pool", None)
+    if own is not None:
+        return own, 0
+    if setup.rows_of_data:
+        x, y = setup.data.x_train, setup.data.y_train
+        entry = _dataset_pool[0]
+        if (entry is not None and entry[0]() is x and entry[1]() is y
+                and entry[2] == mesh):
+            arrays, sent = entry[3], 0
+        else:
+            arrays, sent = _upload(x, y, mesh)
+            _dataset_pool[0] = (
+                weakref.ref(x, _forget_dataset_pool),
+                weakref.ref(y, _forget_dataset_pool), mesh, arrays)
+        row_lists = setup.shard_rows
+    else:
+        xs = [x for x, _ in setup.poisoned]
+        arrays, sent = _upload(
+            np.concatenate(xs).astype(stack_dtype(xs[0]), copy=False),
+            np.concatenate([y for _, y in setup.poisoned]), mesh)
+        ends = np.cumsum([len(x) for x in xs])
+        row_lists = [np.arange(e - len(x), e) for e, x in zip(ends, xs)]
+    pool = _Pool(*arrays, _row_map(row_lists),
+                 np.asarray([len(r) for r in row_lists], np.int32))
+    if not setup.rows_of_data:
+        setup._own_pool = pool
+    return pool, sent
+
+
+def _take_rows(pool, rows):
+    """``pool[rows]``, with a zero row wherever ``rows`` is -1."""
+    live = (rows >= 0).reshape(rows.shape + (1,) * (pool.ndim - 1))
+    return jnp.where(live, pool[jnp.maximum(rows, 0)], 0)
+
+
+def _stage_rows(pool_x, pool_y, rows):
+    return _take_rows(pool_x, rows), _take_rows(pool_y, rows)
+
+
+def _fused_data(setup: _Setup, mesh=None) -> FusedData:
+    """The one-shot fused engine's inputs: every client, the identity map."""
+    K = setup.sim.num_clients
+    return _compact_inputs(setup, np.arange(K), K, mesh)[0]
 
 
 def _client_mesh(sim: SimConfig):
@@ -559,7 +659,7 @@ def _run_fused(
     mesh = _client_mesh(sim)
     if eager and mesh is not None:
         raise ValueError("fused_eager has no client-sharded form; use engine='fused'")
-    data = _fused_data(setup)
+    data = _fused_data(setup, mesh)
     scan_fn, round_fn = _make_setup_sim(setup, server_cfg, mesh)
 
     t_start = time.perf_counter()
@@ -603,28 +703,51 @@ def _run_fused(
 # ---------------------------------------------------------------------------
 
 
-def _compact_inputs(setup: _Setup, kept: np.ndarray, bucket: int):
-    """Gather the kept clients' device inputs into a ``bucket``-row layout.
+def _compact_inputs(setup: _Setup, kept: np.ndarray, bucket: int, mesh=None,
+                    stage: dict | None = None):
+    """Stage the kept clients' device inputs in a ``bucket``-row layout.
 
     ``kept`` is the index map of still-live original client ids (ascending);
     pad rows — the tail up to ``bucket``, plus any ``-1`` slots the per-shard
     plan interleaved at shard-block tails — carry zero shards of length 1,
     zero ``n_k``, benign ``bad`` and id 0 — all inert, since their
     server-state rows are blocked.
+
+    The client stacks are gathered on the device from the resident pool
+    (:func:`_pool`) by a ``(bucket, n_max)`` row map built here, ``-1`` in
+    every pad slot: the same values, bit for bit, as
+    ``compact_stack(*padded_stack(setup.poisoned), kept, pad_to=bucket)``.
+    ``stage``, a span's attributes, gets ``pool`` (``"hit"`` or
+    ``"upload"``) and ``h2d_bytes``: the map, the masks and any pool upload.
     """
-    x_pad, y_pad, lengths = _padded(setup)
     kept = np.asarray(kept)
-    x_c, y_c, len_c = compact_stack(x_pad, y_pad, lengths, kept, pad_to=bucket)
+    if bucket < len(kept):
+        raise ValueError(
+            f"bucket={bucket} is smaller than the {len(kept)} kept client "
+            f"rows; refusing to truncate live clients"
+        )
     live = kept >= 0
+    pool, sent = _pool(setup, mesh)
+    rows = np.full((bucket, pool.rows.shape[1]), -1, np.int32)
+    rows[: len(kept)][live] = pool.rows[kept[live]]
+    # client-sharded, split as place_on_client_mesh places the stacks: each
+    # device gathers its own rows from its replica of the pool
+    x_c, y_c = _jit_on(_stage_rows, mesh, split=True)(pool.x, pool.y, rows)
+    len_c = np.ones((bucket,), np.int32)
+    len_c[: len(kept)][live] = pool.lengths[kept[live]]
     n_k_c = np.zeros((bucket,), np.float32)
     n_k_c[: len(kept)][live] = setup.n_k[kept[live]]
     bad_c = np.zeros((bucket,), bool)
     bad_c[: len(kept)][live] = setup.bad_mask[kept[live]]
     ids_c = np.zeros((bucket,), np.uint32)
     ids_c[: len(kept)][live] = kept[live]
+    if stage is not None:
+        stage["pool"] = "hit" if sent == 0 else "upload"
+        stage["h2d_bytes"] = sent + sum(
+            a.nbytes for a in (rows, len_c, n_k_c, bad_c, ids_c))
     data = FusedData(
-        x=jnp.asarray(x_c),
-        y=jnp.asarray(y_c),
+        x=x_c,
+        y=y_c,
         lengths=jnp.asarray(len_c),
         n_k=jnp.asarray(n_k_c),
         x_test=setup.x_test,
@@ -666,12 +789,14 @@ def _segment_layout(live: np.ndarray, K: int, n_shards: int, mesh):
     return kept, rows * n_shards
 
 
-def _segment_inputs(setup: _Setup, params, state_full, kept, bucket: int, mesh):
+def _segment_inputs(setup: _Setup, params, state_full, kept, bucket: int, mesh,
+                    stage: dict | None = None):
     """A segment's inputs for the ``(kept, bucket)`` layout: the compacted
     data stacks, masks and server state, with params.  Client-sharded, all
     of them are committed to the client mesh
-    (:func:`~repro.fed.engine.place_on_client_mesh`)."""
-    data_c, bad_c, ids_c = _compact_inputs(setup, kept, bucket)
+    (:func:`~repro.fed.engine.place_on_client_mesh`).  ``stage`` is as in
+    :func:`_compact_inputs`."""
+    data_c, bad_c, ids_c = _compact_inputs(setup, kept, bucket, mesh, stage)
     state_c = gather_server_state(state_full, kept, bucket)
     if mesh is None:
         return params, state_c, data_c, bad_c, ids_c
@@ -719,10 +844,14 @@ def _run_fused_segmented(
     compaction in between (DESIGN.md §2).
 
     Between segments the host reads the blocked set (the only device→host
-    sync, O(T / S) of them), gathers the still-live clients' shard stacks /
-    ``n_k`` / reputation posteriors / attack masks into a dense power-of-two
-    bucket via the ``kept`` index map, and re-embeds the compacted
-    ``ServerState`` into the full-K layout afterwards.  Because every
+    sync, O(T / S) of them), gathers the still-live clients' ``n_k`` /
+    reputation posteriors / attack masks into a dense power-of-two bucket
+    via the ``kept`` index map, and re-embeds the compacted ``ServerState``
+    into the full-K layout afterwards.  The shard stacks are gathered on
+    the device from a resident pool of training rows by a row map the host
+    builds from ``kept`` (:func:`_compact_inputs`): no stack is copied on
+    the host, and the dataset's rows go to the device once, not once per
+    experiment.  Because every
     per-client RNG stream is keyed by original client id and dropped rows
     were mask-zeroed in every reduction, the stitched trajectory is
     bit-identical to the one-shot fused scan — but post-blocking segments pay
@@ -780,12 +909,9 @@ def _run_fused_segmented(
                         state_full = scatter_server_state(state_full, state_c, kept)
                     bucket, kept = new_bucket, new_kept
                     params, state_c, data_c, bad_c, ids_c = _segment_inputs(
-                        setup, params, state_full, kept, bucket, mesh
+                        setup, params, state_full, kept, bucket, mesh, stage
                     )
                     stage["rows"] = int((kept >= 0).sum())
-                    # the compacted stacks and masks (_Setup placed the test set)
-                    stage["h2d_bytes"] = int(
-                        sum(a.nbytes for a in (*data_c[:4], bad_c, ids_c)))
             seg.update(bucket=int(bucket), live=len(live))
             with span("fed.segment.call"):
                 seg_fn = _segment_fn(

@@ -285,3 +285,102 @@ def test_client_sharded_fused_trajectory_parity():
     assert out.returncode == 0, out.stdout + out.stderr
     assert "ONE_SHARD_BIT_IDENTICAL" in out.stdout
     assert "FOUR_SHARD_EQUIVALENT" in out.stdout
+
+
+# ---------------------------------------------------------------------------
+# client-sharded staging: the replicated pool and the per-device gather
+# ---------------------------------------------------------------------------
+
+STAGING_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import PartitionSpec as P
+from repro.data import compact_stack, make_spambase_like, padded_stack
+from repro.fed import FusedData, simulator
+from repro.fed.server import ServerConfig
+from repro.fed.simulator import SimConfig, simulate
+from repro.launch.mesh import client_axis
+from repro.utils import spans
+
+K = 20
+data = make_spambase_like(n_train=640, n_test=200, dim=24, seed=0)
+sim = SimConfig(
+    num_clients=K, bad_frac=0.4, scenario="byzantine", rounds=12,
+    local_epochs=1, batch_size=16, hidden=(8,), engine="fused",
+    segment_rounds=4, compact=True, client_shards=4, seed=0,
+)  # all 8 attackers get blocked: 5 rows a shard, then 4
+cfg = ServerConfig(rule="afa", num_clients=K)
+
+
+def host_compact(setup, kept, bucket, mesh=None, stage=None):
+    # the stacks as the host built and sent them before device staging
+    x, y, lengths = compact_stack(*padded_stack(setup.poisoned), kept,
+                                  pad_to=bucket)
+    live = kept >= 0
+    n_k = np.zeros((bucket,), np.float32)
+    bad = np.zeros((bucket,), bool)
+    ids = np.zeros((bucket,), np.uint32)
+    n_k[: len(kept)][live] = setup.n_k[kept[live]]
+    bad[: len(kept)][live] = setup.bad_mask[kept[live]]
+    ids[: len(kept)][live] = kept[live]
+    fdata = FusedData(jnp.asarray(x), jnp.asarray(y), jnp.asarray(lengths),
+                      jnp.asarray(n_k), setup.x_test, setup.y_test)
+    return fdata, jnp.asarray(bad), jnp.asarray(ids)
+
+
+n0 = max((r.span_id for r in spans.records()), default=0)
+staged = simulate(data, sim, cfg)
+stages = [r for r in spans.records()
+          if r.span_id > n0 and r.name == "fed.segment.stage"]
+assert [r.attrs["bucket"] for r in stages] == [20, 16], stages
+assert [r.attrs["pool"] for r in stages] == ["upload", "hit"], stages
+
+# the pool is the dataset, replicated on all four devices
+pool_x = simulator._dataset_pool[0][3][0]
+assert pool_x.sharding.is_fully_replicated
+assert len(pool_x.sharding.device_set) == 4
+assert pool_x.shape == data.x_train.shape
+
+# a compacted layout, -1 slots at shard-block tails: the gathered stacks
+# sit on the client mesh as place_on_client_mesh puts them, equal bit for
+# bit to the host's
+setup = simulator._Setup(data, sim)
+mesh = simulator._client_mesh(sim)
+live = np.asarray([1, 2, 3, 7, 8, 10, 15])
+kept, bucket = simulator._segment_layout(live, K, 4, mesh)
+assert (kept == -1).any()
+got = simulator._compact_inputs(setup, kept, bucket, mesh)
+want = host_compact(setup, kept, bucket)
+for g, w in zip((got[0].x, got[0].y), (want[0].x, want[0].y)):
+    assert g.sharding.spec == P(client_axis(mesh)), g.sharding
+    assert len(g.sharding.device_set) == 4
+    assert np.asarray(g).tobytes() == np.asarray(w).tobytes()
+
+# the same sharded run on the host-built stacks: today's trajectory
+simulator._compact_inputs = host_compact
+host = simulate(data, sim, cfg)
+assert np.array_equal(staged.test_error, host.test_error)
+assert np.array_equal(np.stack(staged.good_mask_history),
+                      np.stack(host.good_mask_history))
+assert np.array_equal(staged.blocked_round, host.blocked_round)
+for a, b in zip(jax.tree_util.tree_leaves(staged.params),
+                jax.tree_util.tree_leaves(host.params)):
+    assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+print("SHARDED_POOL_STAGING_BIT_IDENTICAL")
+"""
+
+
+def test_client_sharded_staging_gathers_from_a_replicated_pool():
+    """A 4-shard segmented run stages its client stacks by gathering, on
+    each device, from the dataset replicated over the client mesh, and runs
+    the trajectory the host-built stacks give, bit for bit."""
+    assert len(jax.devices()) == 1
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC
+    out = subprocess.run(
+        [sys.executable, "-c", STAGING_SCRIPT], capture_output=True,
+        text=True, env=env, timeout=900,
+    )
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "SHARDED_POOL_STAGING_BIT_IDENTICAL" in out.stdout

@@ -10,7 +10,7 @@ import pytest
 from repro.data import make_mnist_like
 from repro.fed import ServerConfig, SimConfig
 from repro.fed.api import run
-from repro.fed.simulator import first_segment, fused_inputs
+from repro.fed.simulator import first_segment
 from repro.utils import spans
 
 SEGMENT_CHILDREN = ["fed.segment.layout", "fed.segment.call",
@@ -62,13 +62,16 @@ def test_segmented_run_span_tree(data):
     (setup,) = [r for r in recs if r.name == "fed.setup"]
     assert setup.attrs["h2d_bytes"] == 100 * 64 * 4 + 100 * 4  # test x and y
 
-    x_pad = fused_inputs(data, sim).data
-    # x, y and lengths rows, n_k (f32), the byzantine mask (bool), ids (u32)
-    row_bytes = (x_pad.x.nbytes + x_pad.y.nbytes + x_pad.lengths.nbytes) // 10 + 9
+    # a row of the layout: its 60 pool rows in the row map (i32), its
+    # length (i32), n_k (f32), the byzantine mask (bool) and its id (u32)
+    row_bytes = 60 * 4 + 13
+    # the pool: the dataset's training rows, x f32 and y i32, sent once
+    pool_bytes = 600 * 64 * 4 + 600 * 4
     segments = sorted((r for r in recs if r.name == "fed.segment"),
                       key=lambda r: r.t0)
     assert [s.attrs["seg_start"] for s in segments] == [0, 4, 8]
     prev_bucket = None
+    pools = []
     for seg in segments:
         names = [r.name for r in sorted(children(seg), key=lambda r: r.t0)]
         staged = seg.attrs["bucket"] != prev_bucket
@@ -78,10 +81,15 @@ def test_segmented_run_span_tree(data):
             (st,) = [r for r in children(seg) if r.name == "fed.segment.stage"]
             assert st.attrs["bucket"] == seg.attrs["bucket"]
             assert st.attrs["rows"] == seg.attrs["live"]
-            assert st.attrs["h2d_bytes"] == row_bytes * seg.attrs["bucket"]
+            uploaded = pool_bytes if st.attrs["pool"] == "upload" else 0
+            assert st.attrs["h2d_bytes"] == (
+                row_bytes * seg.attrs["bucket"] + uploaded)
+            pools.append(st.attrs["pool"])
         prev_bucket = seg.attrs["bucket"]
     assert segments[0].attrs["bucket"] == 10
     assert segments[-1].attrs["bucket"] == 8  # clients were compacted out
+    # the dataset goes to the device at the first staging, and only then
+    assert pools == ["upload", "hit"]
 
 
 def test_ring_stays_bounded():
