@@ -32,12 +32,18 @@ ALIASES = {
     "hubert-xlarge": "hubert_xlarge",
 }
 
+# client-workload configurations beyond the assigned set (no dry-run shapes)
+WORKLOAD_ALIASES = {
+    "granite-4.0-h-small": "granite4h_small",
+}
+
 # the paper's own experimental models
 PAPER_IDS = ["paper_mnist_dnn", "paper_spambase_dnn"]
 
 
 def get_config(arch: str):
-    mod_name = ALIASES.get(arch, arch.replace("-", "_").replace(".", "_"))
+    mod_name = ALIASES.get(arch) or WORKLOAD_ALIASES.get(
+        arch, arch.replace("-", "_").replace(".", "_"))
     mod = importlib.import_module(f"repro.configs.{mod_name}")
     return mod.CONFIG
 

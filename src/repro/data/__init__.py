@@ -4,6 +4,7 @@ from repro.data.synthetic import (
     make_mnist_like,
     make_spambase_like,
     make_token_stream,
+    markov_sequences,
 )
 from repro.data.sharding import (
     compact_stack,
@@ -22,6 +23,7 @@ __all__ = [
     "make_mnist_like",
     "make_spambase_like",
     "make_token_stream",
+    "markov_sequences",
     "iid_shards",
     "iid_shard_indices",
     "dirichlet_shards",

@@ -94,3 +94,22 @@ def make_token_stream(seed: int = 0, vocab: int = 256, n: int = 200_000) -> Toke
         row = toks[i - 1]
         toks[i] = nxt[row, rng.choice(16, p=trans[row])]
     return TokenStream(toks)
+
+
+def markov_sequences(seed: int, vocab: int, n: int, length: int) -> np.ndarray:
+    """``(n, length)`` int32 token sequences, each an independent chain of
+    :func:`make_token_stream`'s kind of sparse bigram-markov source (one
+    transition table for all, drawn from ``seed``).  The chains step
+    together, so a corpus of a million tokens takes ``length`` vectorised
+    steps instead of a Python loop over every token."""
+    rng = np.random.default_rng(seed)
+    cum = np.cumsum(rng.dirichlet(np.full(16, 0.5), size=vocab), axis=1)
+    nxt = rng.integers(0, vocab, size=(vocab, 16))
+    toks = np.empty((n, length), np.int32)
+    toks[:, 0] = rng.integers(0, vocab, size=n)
+    for i in range(1, length):
+        prev = toks[:, i - 1]
+        u = rng.random(n)[:, None]
+        pick = np.minimum((u > cum[prev]).sum(axis=1), 15)
+        toks[:, i] = nxt[prev, pick]
+    return toks

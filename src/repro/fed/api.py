@@ -27,7 +27,8 @@ Routing rules:
   (``data`` must be a :class:`~repro.data.SyntheticClassification`);
   ``seeds`` selects the vmapped fused sweep.
 * any other workload -> the LLM fused driver (``data`` may be a prebuilt
-  :class:`~repro.fed.engine.FusedData`); extra keyword args
+  :class:`~repro.fed.engine.FusedData` or a corpus of token sequences, and
+  a ``server`` given sets the rule's options); extra keyword args
   (``local_steps``, ``samples_per_client``, ``seq``, ...) pass through.
 
 The old names still work as thin shims that emit ``DeprecationWarning`` and
@@ -99,6 +100,7 @@ def run(
     driver's result dict.
     """
     workload = _resolve_workload(workload, workload_kwargs)
+    given_server = server
     if server is None:
         server = ServerConfig(num_clients=sim.num_clients)
 
@@ -138,6 +140,7 @@ def run(
             scenario=sim.scenario,
             rule=server.rule,
             data=data,
+            server=given_server,
         )
         llm_kwargs.update(extra)  # samples_per_client / seq / n_test / overrides
         return simulate_llm(workload, **llm_kwargs)

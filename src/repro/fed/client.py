@@ -61,7 +61,9 @@ def local_sgd_frozen(
     a *traced* argument — not a Python closure — so the jit identity of the
     step is stable across reconstruction and the frozen tree is never baked
     into the executable as a constant.  The RNG stream is spelled exactly
-    like :func:`local_sgd`'s (one split per step, dropout or not)."""
+    like :func:`local_sgd`'s (one split per step, dropout or not).  The loss
+    returns ``(loss, aux)``; the result is ``(params, aux)`` with ``aux``
+    stacked over the steps."""
     opt = sgd_momentum(lr, momentum)
     opt_state = opt.init(params)
 
@@ -69,12 +71,13 @@ def local_sgd_frozen(
         p, s, key = carry
         mb = xs
         key, sub = jax.random.split(key)
-        g = jax.grad(
-            lambda q: loss_fn(frozen, q, mb, dropout_rng=sub if dropout else None)
+        g, aux = jax.grad(
+            lambda q: loss_fn(frozen, q, mb, dropout_rng=sub if dropout else None),
+            has_aux=True,
         )(p)
         upd, s = opt.update(g, s, p)
         p = jax.tree_util.tree_map(lambda a, u: a + u.astype(a.dtype), p, upd)
-        return (p, s, key), None
+        return (p, s, key), aux
 
-    (params, _, _), _ = jax.lax.scan(step, (params, opt_state, rng), batches)
-    return params
+    (params, _, _), aux = jax.lax.scan(step, (params, opt_state, rng), batches)
+    return params, aux

@@ -60,7 +60,7 @@ adapter tree for the LLM workload.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -141,7 +141,10 @@ def _train_and_attack(
     engines cannot drift apart.  ``client_ids`` maps rows to original client
     ids under compaction (None = identity layout); ``client_axis`` names the
     mesh axis when the stack is client-sharded (alie/ipm psum their benign
-    moments over it)."""
+    moments over it).  Returns ``(proposals, stats)``: ``stats`` are the
+    workload's per-client local-training statistics
+    (``local_update_with_stats``, leading axis K), zero in the rows that did
+    not train; None for a workload that reports none."""
     K = train_mask.shape[0]
     # the reference point attacks perturb and non-trainers hold: the current
     # params projected to proposal space (identity for full-param workloads,
@@ -149,14 +152,18 @@ def _train_and_attack(
     w_prev = workload.codec.proposal_of(params)
 
     def train_one(cbatch, ckey):
-        return workload.local_update(cfg, params, cbatch, ckey)
+        return workload.local_update_with_stats(cfg, params, cbatch, ckey)
 
     with jax.named_scope("local_update"):
-        proposals = jax.vmap(train_one)(batch, keys)
+        proposals, stats = jax.vmap(train_one)(batch, keys)
         # non-trainers hold w_t until the attack layer overwrites their row
         proposals = tree_select_rows(
             train_mask, proposals, tree_broadcast_clients(w_prev, K)
         )
+        # the workload's statistics, zero where a client did not train
+        stats = jax.tree_util.tree_map(
+            lambda s: jnp.where(train_mask.reshape((K,) + (1,) * (s.ndim - 1)), s, 0),
+            stats)
     with jax.named_scope("attack"):
         return apply_update_attack(
             cfg.scenario, proposals, w_prev, bad_mask, benign_mask, akey,
@@ -165,7 +172,7 @@ def _train_and_attack(
             eps=cfg.ipm_eps,
             client_ids=client_ids,
             axis_name=client_axis,
-        )
+        ), stats
 
 
 @functools.lru_cache(maxsize=64)
@@ -185,7 +192,7 @@ def make_train_attack_step(workload, cfg: EngineConfig):
         return _train_and_attack(
             workload, cfg, params, batch, keys, train_mask, bad_mask,
             benign_mask, akey,
-        )
+        )[0]
 
     return step
 
@@ -215,6 +222,10 @@ class FusedTrajectory(NamedTuple):
     # (T, K) f32 — AFA's final-iteration cosine similarities each round
     # (zeros for rules that screen on something else)
     similarities: jnp.ndarray
+    # per round, the workload's per-client local-training statistics, zero
+    # for clients that did not train (``ClientWorkload.local_update_with_stats``;
+    # None for a workload that reports none)
+    workload_stats: Any = None
 
 
 def _gather_rows(stack, idx):
@@ -241,8 +252,9 @@ def _propose_round(
     computes client submissions outside the fused scan: participation masks,
     the device minibatch draw, vmapped local training, and the update-level
     attack — everything up to (but not including) aggregation.  Returns
-    ``(proposals, mask0)`` with ``proposals`` a stacked proposal-space pytree
-    and ``mask0`` the live-participant mask."""
+    ``(proposals, mask0, stats)`` with ``proposals`` a stacked proposal-space
+    pytree, ``mask0`` the live-participant mask and ``stats`` the workload's
+    per-client local-training statistics (see :func:`_train_and_attack`)."""
     skip_bad = cfg.scenario in UPDATE_ATTACK_SCENARIOS
     mask0 = ~blocked
     train_mask = mask0 & ~bad if skip_bad else mask0
@@ -260,7 +272,7 @@ def _propose_round(
             lambda k, n: jax.random.randint(k, (batch_s, batch_b), 0, n)
         )(bkeys, data.lengths)
         batch = {"x": _gather_rows(data.x, idx), "y": _gather_rows(data.y, idx)}
-    proposals = _train_and_attack(
+    proposals, stats = _train_and_attack(
         workload, cfg, params, batch,
         client_keys_traced(seed, rnd, ids, num_clients_total),
         train_mask, bad & mask0, mask0 & ~bad,
@@ -268,7 +280,7 @@ def _propose_round(
         client_ids=ids,
         client_axis=client_axis,
     )
-    return proposals, mask0
+    return proposals, mask0, stats
 
 
 @functools.lru_cache(maxsize=32)
@@ -291,7 +303,7 @@ def make_packed_propose_fn(
 
     @jax.jit
     def propose(params, blocked, rnd, seed, data: FusedData, bad, client_ids):
-        proposals, _ = _propose_round(
+        proposals, _, _ = _propose_round(
             workload, cfg, num_clients_total, batch_s, batch_b, None,
             params, blocked, rnd, seed, data, bad, client_ids,
         )
@@ -332,7 +344,7 @@ def _round_body(
     from repro.fed.server import server_step
 
     params, state = carry
-    proposals, mask0 = _propose_round(
+    proposals, mask0, stats = _propose_round(
         workload, cfg, num_clients_total, batch_s, batch_b, client_axis,
         params, state.reputation.blocked, rnd, seed, data, bad, client_ids,
     )
@@ -375,7 +387,8 @@ def _round_body(
     sims = getattr(res, "similarities", None)
     if sims is None:
         sims = jnp.zeros(res.good_mask.shape, jnp.float32)
-    out = FusedTrajectory(err, res.good_mask, state.reputation.blocked, sims)
+    out = FusedTrajectory(err, res.good_mask, state.reputation.blocked, sims,
+                          stats)
     return (params, state), out
 
 
@@ -398,6 +411,7 @@ def make_fused_sim(
     beta0: float = 3.0,
     agg_layout: str = "packed",
     client_mesh=None,
+    keep_round1: bool = False,
 ):
     """Build the fused T-round simulation (DESIGN.md §2).
 
@@ -408,6 +422,9 @@ def make_fused_sim(
       carry ``(params, ServerState)``, with minibatch indices drawn on device
       and the per-round (test error, good_mask, blocked) trajectory emitted
       as scan outputs.  ``seed`` may be traced — ``run_sweep`` vmaps it.
+      With ``keep_round1`` the carry also holds the params after round 1 and
+      ``scan_fn`` returns them last, ``(params_T, state_T, traj, params_1)``:
+      one round of the timed program that a reference can replay.
     * ``round_fn(carry, rnd, seed, data) -> (carry', out)`` — the identical
       round body, jit'd standalone so it can run eagerly one round at a
       time: the bit-equivalence reference for the scan
@@ -441,7 +458,7 @@ def make_fused_sim(
         workload, cfg, rule, opts, float(delta_block),
         int(num_clients), int(num_rounds), int(batch_s), int(batch_b),
         tuple(bool(b) for b in np.asarray(bad_mask)), float(alpha0), float(beta0),
-        agg_layout, client_mesh,
+        agg_layout, client_mesh, bool(keep_round1),
     )
 
 
@@ -484,7 +501,7 @@ def _validate_client_mesh(mesh, cfg: EngineConfig, rule, agg_layout, num_rows):
 def _make_fused_sim_cached(
     workload, cfg: EngineConfig, rule, opts, delta_block,
     num_clients, num_rounds, batch_s, batch_b, bad_tuple, alpha0, beta0,
-    agg_layout, client_mesh=None,
+    agg_layout, client_mesh=None, keep_round1=False,
 ):
     K = num_clients
     bad = jnp.asarray(bad_tuple)
@@ -495,29 +512,53 @@ def _make_fused_sim_cached(
         K, batch_s, batch_b, axis,
     )
 
+    codec = workload.codec
+
     def round_fn(carry, rnd, seed, data: FusedData):
         return body(carry, rnd, seed, data, bad, ids)
 
     def _scan(params0, state0, seed, data, bad_rows, id_rows):
+        """The rounds, carrying only the proposal-space part of the params:
+        what the codec leaves out (a delta workload's frozen base) is read
+        from ``params0`` by every round, never copied through the carry or
+        out of the program (identity codec: the carry is the params).  With
+        ``keep_round1`` the carry ends in the proposal after round 1."""
+        def step(c, r):
+            w, state, *first = c
+            (params, state), out = body(
+                (codec.apply(params0, w), state), r, seed, data, bad_rows, id_rows)
+            w = codec.proposal_of(params)
+            first = [jax.tree_util.tree_map(lambda f, n: jnp.where(r == 0, n, f), f, w)
+                     for f in first]
+            return (w, state, *first), out
+
+        w0 = codec.proposal_of(params0)
         return jax.lax.scan(
-            lambda c, r: body(c, r, seed, data, bad_rows, id_rows),
-            (params0, state0),
+            step, (w0, state0) + ((w0,) if keep_round1 else ()),
             jnp.arange(num_rounds, dtype=jnp.int32),
         )
+
+    def with_params(scan_jit):
+        def scan_fn(params0, seed, data: FusedData):
+            w, state, traj, *first = scan_jit(params0, seed, data)
+            return (codec.apply(params0, w), state, traj,
+                    *[codec.apply(params0, f) for f in first])
+
+        return scan_fn
 
     if client_mesh is None:
 
         @jax.jit
-        def scan_fn(params0, seed, data: FusedData):
+        def scan_jit(params0, seed, data: FusedData):
             from repro.fed.server import init_server_state
 
             state0 = init_server_state(K, alpha0, beta0)
-            (params, state), traj = _scan(params0, state0, seed, data, bad, ids)
-            return params, state, traj
+            (w, state, *first), traj = _scan(params0, state0, seed, data, bad, ids)
+            return (w, state, traj, *first)
 
         # the eager form is jit'd HERE, inside the cache, so repeated
         # fused_eager simulations reuse its compile like the scan does
-        return scan_fn, jax.jit(round_fn)
+        return with_params(scan_jit), jax.jit(round_fn)
 
     from repro.launch.mesh import client_axis
 
@@ -531,23 +572,23 @@ def _make_fused_sim_cached(
         # init is uniform per client, so building it at local width IS the
         # shard's slice of the full-K initial state
         state0 = init_server_state(K // shards, alpha0, beta0)
-        (params, state), traj = _scan(params0, state0, seed, data, bad_rows, id_rows)
-        return params, state, traj
+        (w, state, *first), traj = _scan(params0, state0, seed, data, bad_rows, id_rows)
+        return (w, state, traj, *first)
 
     P = jax.sharding.PartitionSpec
     sharded = jax.shard_map(
         shard_body, mesh=client_mesh,
         in_specs=(P(), P(), data_in, P(axis), P(axis)),
-        out_specs=(P(), state_out, traj_out),
+        out_specs=(P(), state_out, traj_out) + ((P(),) if keep_round1 else ()),
         check_vma=False,
     )
 
     @jax.jit
-    def scan_fn(params0, seed, data: FusedData):
+    def scan_jit(params0, seed, data: FusedData):
         return sharded(params0, jnp.asarray(seed, jnp.uint32), data, bad, ids)
 
     # no eager per-round form for the sharded engine: the scan is the product
-    return scan_fn, None
+    return with_params(scan_jit), None
 
 
 def _client_shard_specs(axis: str):

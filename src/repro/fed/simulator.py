@@ -676,7 +676,7 @@ def _run_fused(
             carry, out = step(carry, jnp.int32(rnd), jnp.uint32(sim.seed), data)
             outs.append(out)
         params, state = carry
-        traj = FusedTrajectory(*[jnp.stack(ls) for ls in zip(*outs)])
+        traj = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
     else:
         params, state, traj = scan_fn(setup.params0, jnp.uint32(sim.seed), data)
     jax.block_until_ready(traj)

@@ -52,6 +52,7 @@ import numpy as np
 
 from repro.fed.client import local_sgd, local_sgd_frozen
 from repro.fed.dnn import dnn_error, dnn_loss, init_dnn
+from repro.utils.spans import span
 from repro.utils.trees import PackSpec, pack_spec, tree_size
 
 
@@ -152,6 +153,13 @@ class ClientWorkload:
         """
         raise NotImplementedError
 
+    def local_update_with_stats(self, cfg, params, batches, key):
+        """``(proposal, stats)``: ``stats`` is a pytree of this client's
+        local-training statistics, which the fused engines sum over the
+        clients that trained into ``FusedTrajectory.workload_stats`` (None:
+        the workload reports none)."""
+        return self.local_update(cfg, params, batches, key), None
+
     def eval_metric(self, params, x_test, y_test):
         """Scalar error in [0, 1] on the held-out set."""
         raise NotImplementedError
@@ -217,9 +225,12 @@ class DnnWorkload(ClientWorkload):
 #
 # Clients hold a frozen transformer base (models/ stack: vmapped per-layer
 # init, jax.checkpoint'd scan over layers) and train only LoRA adapters on
-# the stacked attention projections: for each target matrix W (L, d_in,
-# d_out) an A (L, d_in, r) / B (L, r, d_out) pair with B zero-initialised,
-# merged as W + (alpha/r) * A @ B per layer.  The proposal space is the
+# the stacked attention and state-space projections: for each target matrix
+# W (L, d_in, d_out) an A (L, d_in, r) / B (L, r, d_out) pair with B
+# zero-initialised.  Training applies them unmerged inside each layer,
+# x @ W + (alpha/r) (x @ A) @ B (``attach_lora``; ``models.layers.linear``),
+# so the frozen base stays one unbatched copy under the client vmap;
+# ``merge_lora`` builds W + (alpha/r) A @ B for export.  The proposal space is the
 # adapter tree, so the packed aggregation buffer is (K, D_adapter) with
 # D_adapter ≪ D, and every update-level attack (byzantine/alie/ipm) operates
 # on adapters for free — w_prev handed to the attack layer is the current
@@ -277,6 +288,31 @@ def init_lora_adapters(key, layers, targets, rank: int):
     return adapters
 
 
+def _is_site(anode) -> bool:
+    return isinstance(anode, dict) and set(anode) == {"a", "b"}
+
+
+def attach_lora(layers, adapters, scaling: float):
+    """Layer stack with each adapted leaf W replaced by ``{"w": W, "a":
+    scaling * A, "b": B}``, which ``models.layers.linear`` applies unmerged
+    (``x @ W + (x @ scaling A) @ B``).  Nothing is copied: W is the frozen
+    base's own array, shared by every client under the vmap."""
+
+    def walk(node, anode):
+        if not isinstance(node, dict):
+            return node
+        out = {}
+        for k, v in node.items():
+            sub = anode.get(k) if isinstance(anode, dict) else None
+            if _is_site(sub) and not isinstance(v, dict):
+                out[k] = {"w": v, "a": sub["a"] * scaling, "b": sub["b"]}
+            else:
+                out[k] = walk(v, sub)
+        return out
+
+    return walk(layers, adapters)
+
+
 def merge_lora(layers, adapters, scaling: float):
     """Effective layer stack: target leaves get ``W + scaling * A @ B``
     (batched over the layer axis), everything else passes through."""
@@ -287,7 +323,7 @@ def merge_lora(layers, adapters, scaling: float):
         out = {}
         for k, v in node.items():
             sub = anode.get(k) if isinstance(anode, dict) else None
-            if isinstance(sub, dict) and set(sub) == {"a", "b"} and not isinstance(v, dict):
+            if _is_site(sub) and not isinstance(v, dict):
                 delta = jnp.einsum("lir,lro->lio", sub["a"], sub["b"]) * scaling
                 out[k] = (v.astype(jnp.float32) + delta).astype(v.dtype)
             else:
@@ -303,18 +339,27 @@ def _merged_params(base, adapters, scaling: float):
     return eff
 
 
+def _attached_params(base, adapters, scaling: float):
+    eff = dict(base)
+    eff["layers"] = attach_lora(base["layers"], adapters, scaling)
+    return eff
+
+
 @functools.lru_cache(maxsize=8)
 def _lora_loss_fn(model_cfg, targets, scaling: float):
     """Loss over (frozen base, adapters) with the engine's ``{"x","y"}``
-    batch convention mapped to the LM's ``{"tokens","labels"}``.  Accepts
-    (and ignores) ``dropout_rng`` so the client RNG stream is spelled exactly
-    like the DNN path's."""
+    batch convention mapped to the LM's ``{"tokens","labels"}``, returned
+    with the experts every token chose (``{"experts": ...}``) where the
+    model reports them, ``{}`` where it does not.  Accepts (and ignores)
+    ``dropout_rng`` so the client RNG stream is spelled exactly like the DNN
+    path's."""
     model = _lora_model(model_cfg)
 
     def loss(base, adapters, mb, *, dropout_rng=None):
         del dropout_rng  # the LM stack is deterministic; key split still happens
-        eff = _merged_params(base, adapters, scaling)
-        return model.loss_fn(eff, {"tokens": mb["x"], "labels": mb["y"]})[0]
+        eff = _attached_params(base, adapters, scaling)
+        value, metrics = model.loss_fn(eff, {"tokens": mb["x"], "labels": mb["y"]})
+        return value, {k: metrics[k] for k in ("experts",) if k in metrics}
 
     return loss
 
@@ -327,7 +372,8 @@ class TransformerLoraWorkload(ClientWorkload):
     model_cfg: Any  # repro.models.ModelConfig (frozen dataclass, hashable)
     rank: int = 4
     alpha: float = 8.0
-    targets: tuple = ("wq", "wk", "wv", "wo")
+    # attention q/k/v/o and the Mamba-2 mixer's input and output projections
+    targets: tuple = ("wq", "wk", "wv", "wo", "in_proj", "out_proj")
 
     name = "lora"
     codec = ADAPTER_CODEC
@@ -348,17 +394,26 @@ class TransformerLoraWorkload(ClientWorkload):
         return {"base": base, "adapters": adapters}
 
     def local_update(self, cfg, params, batches, key):
+        return self.local_update_with_stats(cfg, params, batches, key)[0]
+
+    def local_update_with_stats(self, cfg, params, batches, key):
+        """Stats, for a model that routes: ``experts``, the expert ids each
+        token chose in each layer at each local step, and ``trained`` (True;
+        the engine zeroes both for a client that did not train)."""
         loss = _lora_loss_fn(self.model_cfg, self.targets, self.scaling)
-        return local_sgd_frozen(
+        adapters, aux = local_sgd_frozen(
             loss, params["base"], params["adapters"], batches, key,
             lr=cfg.lr, momentum=cfg.momentum, dropout=cfg.dropout,
         )
+        if not aux:
+            return adapters, None
+        return adapters, {"experts": aux["experts"], "trained": jnp.ones((), bool)}
 
     def eval_metric(self, params, x_test, y_test):
         """Masked next-token error: fraction of (label >= 0) positions where
         the greedy prediction misses."""
         model = _lora_model(self.model_cfg)
-        eff = _merged_params(params["base"], params["adapters"], self.scaling)
+        eff = _attached_params(params["base"], params["adapters"], self.scaling)
         logits = model.forward(eff, {"tokens": x_test})
         pred = jnp.argmax(logits, axis=-1)
         mask = y_test >= 0
@@ -449,6 +504,29 @@ def make_llm_fused_data(
     )
 
 
+def llm_data_from_sequences(seqs, *, clients: int, samples_per_client: int,
+                            n_test: int, seed: int):
+    """Host :class:`~repro.fed.engine.FusedData` for one experiment from a
+    corpus of token sequences ``(N, seq + 1)``: ``seed`` permutes the corpus,
+    the first ``clients * samples_per_client`` sequences become the clients'
+    shards (inputs ``s[:-1]``, next-token labels ``s[1:]``) and the next
+    ``n_test`` the held-out batch."""
+    from repro.fed.engine import FusedData
+
+    need = clients * samples_per_client + n_test
+    if len(seqs) < need:
+        raise ValueError(f"corpus of {len(seqs)} sequences; the experiment needs {need}")
+    pick = np.asarray(seqs)[np.random.default_rng(seed).permutation(len(seqs))[:need]]
+    train = pick[: clients * samples_per_client].reshape(clients, samples_per_client, -1)
+    test = pick[clients * samples_per_client:]
+    lengths = np.full((clients,), samples_per_client, np.int32)
+    return FusedData(
+        x=train[..., :-1].astype(np.int32), y=train[..., 1:].astype(np.int32),
+        lengths=lengths, n_k=lengths.astype(np.float32),
+        x_test=test[:, :-1].astype(np.int32), y_test=test[:, 1:].astype(np.int32),
+    )
+
+
 def run_llm_simulation(
     workload: TransformerLoraWorkload,
     **kwargs,
@@ -483,52 +561,120 @@ def simulate_llm(
     scenario: str = "byzantine",
     rule: str = "afa",
     data=None,
+    server=None,
+    params0=None,
+    keep_round1: bool = False,
 ):
     """Run the fused T-round simulation on the LLM workload and summarize.
 
     The first ``byzantine`` clients run the update-level attack ``scenario``
     (on the *adapter* proposals — the attack layer is workload-agnostic);
     AFA screens the packed ``(K, D_adapter)`` buffer, reputation accumulates,
-    and blocking kicks the attackers out of the aggregate.  Returns a dict of
-    host numpy results (trajectory, blocking, buffer geometry).
+    and blocking kicks the attackers out of the aggregate.  ``data`` is a
+    prebuilt ``FusedData``, a corpus of token sequences ``(N, seq + 1)``
+    that ``seed`` shards (:func:`llm_data_from_sequences`), or None (the
+    synthetic token stream).  ``server`` (a ``ServerConfig``) sets the
+    rule's options; None builds one for ``rule``.  ``params0`` are the
+    initial ``{"base", "adapters"}`` (a pretrained base to fine-tune); None
+    draws them from ``seed`` (``workload.init_params``).  Returns a dict of
+    host numpy results (trajectory, blocking, buffer geometry), the final
+    ``params`` on the device, with ``keep_round1`` the params after round 1
+    (``params_round1``), and for a model that routes, ``experts``
+    ``(T, K, steps, L, batch, seq, top_k)`` (the ids every token chose in
+    every layer) and ``trained`` ``(T, K)`` (which rows they belong to).
+
+    Spans: ``fed.setup`` (data to the device, ``h2d_bytes``; the model's
+    init where no ``params0`` is given), ``fed.llm.call`` (the scan's dispatch), ``fed.llm.wait`` (until its
+    trajectory is ready), ``fed.result``; and, for a workload whose model
+    reports routing, one ``fed.moe.route`` record (see
+    :func:`_record_route`).
     """
-    from repro.fed.engine import EngineConfig, make_fused_sim
+    from repro.fed.engine import EngineConfig, FusedData, make_fused_sim
     from repro.fed.server import ServerConfig, make_rule_options
 
-    if data is None:
-        data = make_llm_fused_data(
-            workload.model_cfg, clients=clients,
-            samples_per_client=samples_per_client, seq=seq, n_test=n_test,
-            seed=seed,
+    with span("fed.setup") as attrs:
+        if data is None:
+            data = make_llm_fused_data(
+                workload.model_cfg, clients=clients,
+                samples_per_client=samples_per_client, seq=seq, n_test=n_test,
+                seed=seed,
+            )
+        elif not isinstance(data, FusedData):
+            data = llm_data_from_sequences(
+                data, clients=clients, samples_per_client=samples_per_client,
+                n_test=n_test, seed=seed)
+        attrs["h2d_bytes"] = sum(
+            int(a.nbytes) for a in data if not isinstance(a, jax.Array))
+        data = FusedData(*(jnp.asarray(a) for a in data))
+        bad = np.zeros((clients,), bool)
+        bad[:byzantine] = True
+
+        cfg = EngineConfig(scenario=scenario, lr=lr, momentum=0.9, dropout=False)
+        scfg = server if server is not None else ServerConfig(
+            rule=rule, num_clients=clients,
+            num_byzantine=max(byzantine, 1), trim=max(min(byzantine, (clients - 1) // 2), 1),
         )
-    bad = np.zeros((clients,), bool)
-    bad[:byzantine] = True
+        scan_fn, _ = make_fused_sim(
+            workload, cfg, rule=scfg.rule, opts=make_rule_options(scfg, clients),
+            delta_block=scfg.delta_block, num_clients=clients, num_rounds=rounds,
+            batch_s=local_steps, batch_b=batch, bad_mask=bad, agg_layout="packed",
+            alpha0=scfg.alpha0, beta0=scfg.beta0, keep_round1=keep_round1,
+        )
+        if params0 is None:
+            params0 = _init_fn(workload)(jax.random.PRNGKey(seed))
+    with span("fed.llm.call"):
+        params, state, traj, *first = scan_fn(params0, np.uint32(seed), data)
+    with span("fed.llm.wait"):
+        jax.block_until_ready(traj)
 
-    cfg = EngineConfig(scenario=scenario, lr=lr, momentum=0.9, dropout=False)
-    scfg = ServerConfig(
-        rule=rule, num_clients=clients,
-        num_byzantine=max(byzantine, 1), trim=max(min(byzantine, (clients - 1) // 2), 1),
-    )
-    scan_fn, _ = make_fused_sim(
-        workload, cfg, rule=rule, opts=make_rule_options(scfg, clients),
-        delta_block=scfg.delta_block, num_clients=clients, num_rounds=rounds,
-        batch_s=local_steps, batch_b=batch, bad_mask=bad, agg_layout="packed",
-    )
-    params0 = workload.init_params(jax.random.PRNGKey(seed))
-    params, state, traj = scan_fn(params0, np.uint32(seed), data)
-    jax.block_until_ready(traj.test_error)
+    with span("fed.result"):
+        d_adapter = workload.proposal_dim(params0)
+        d_total = workload.param_dim(params0)
+        good_frac = np.asarray(traj.good_mask, np.float32).mean(axis=1)
+        out = {
+            "test_error": np.asarray(traj.test_error),
+            "good_frac": good_frac,
+            "good_mask": np.asarray(traj.good_mask),
+            "similarities": np.asarray(traj.similarities),
+            "blocked": np.asarray(traj.blocked),
+            "rounds_blocked": np.asarray(state.rounds_blocked),
+            "bad_mask": bad,
+            "adapter_dim": int(d_adapter),
+            "param_dim": int(d_total),
+            "adapter_fraction": float(d_adapter) / float(d_total),
+            "params": params,
+        }
+        if first:
+            out["params_round1"] = first[0]
+        if traj.workload_stats is not None:
+            out["experts"] = np.asarray(traj.workload_stats["experts"])
+            out["trained"] = np.asarray(traj.workload_stats["trained"]).astype(bool)
+    if "experts" in out:
+        _record_route(out["experts"], out["trained"], workload.model_cfg)
+    return out
 
-    d_adapter = workload.proposal_dim(params0)
-    d_total = workload.param_dim(params0)
-    good_frac = np.asarray(traj.good_mask, np.float32).mean(axis=1)
-    return {
-        "test_error": np.asarray(traj.test_error),
-        "good_frac": good_frac,
-        "blocked": np.asarray(traj.blocked),
-        "rounds_blocked": np.asarray(state.rounds_blocked),
-        "bad_mask": bad,
-        "adapter_dim": int(d_adapter),
-        "param_dim": int(d_total),
-        "adapter_fraction": float(d_adapter) / float(d_total),
-        "params": params,
-    }
+
+@functools.lru_cache(maxsize=8)
+def _init_fn(workload):
+    """``workload.init_params`` as one compiled program (op by op, a model
+    of this size dispatches hundreds of small random draws)."""
+    return jax.jit(workload.init_params)
+
+
+def _record_route(experts, trained, model_cfg) -> None:
+    """One ``fed.moe.route`` record for the experiment, over every round,
+    client that trained, local step, layer and token: ``tokens_held`` the
+    token-choices this chip's experts (``model_cfg.held_range``) computed;
+    ``held_share`` their share of all token-choices (top-k per token and
+    layer; 9/72 = 12.5% under uniform routing); ``max_over_mean`` the
+    largest (layer, held expert) count over the mean of those counts."""
+    lo, hi = model_cfg.held_range
+    chosen = np.asarray(experts)[np.asarray(trained, bool)]  # (n, S, L, B, seq, k)
+    per = np.stack([
+        np.bincount(chosen[:, :, layer].ravel(), minlength=model_cfg.num_experts)[lo:hi]
+        for layer in range(chosen.shape[2])]).astype(np.float64)  # (L, held)
+    held = float(per.sum())
+    with span("fed.moe.route", tokens_held=int(held),
+              held_share=held / max(float(chosen.size), 1.0),
+              max_over_mean=float(per.max() / max(per.mean(), 1e-30))):
+        pass

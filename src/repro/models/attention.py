@@ -59,6 +59,8 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 512,
     parallel_q: bool = False,
+    scale: float | None = None,
+    remat_tiles: bool = False,
 ):
     """Blocked attention with online softmax.  ``prefix_len`` makes the first
     ``prefix_len`` key positions visible to every query (prefix-LM / VLM).
@@ -67,7 +69,10 @@ def flash_attention(
     them sequentially and pins the block axis to the *model* mesh axis when
     divisible — sequence parallelism for MQA/low-head-count archs whose head
     axis cannot shard the mesh.  Peak memory rises by the number of in-flight
-    q blocks; pick ``block_q = Lq / mesh_model`` so each chip owns one block."""
+    q blocks; pick ``block_q = Lq / mesh_model`` so each chip owns one block.
+    ``scale`` multiplies the scores (default ``1/sqrt(head_dim)``).
+    ``remat_tiles`` checkpoints each key-block step, so the backward pass
+    recomputes one tile's scores at a time instead of keeping every tile."""
     b, lq, hq, d = q.shape
     _, lk, hkv, _ = k.shape
     g = hq // hkv
@@ -83,7 +88,7 @@ def flash_attention(
     qs = _split_heads(qp, hkv).reshape(b, nq, block_q, hkv, g, d).transpose(1, 0, 2, 3, 4, 5)
     ks = kp.reshape(b, nk, block_k, hkv, d)
     vs = vp.reshape(b, nk, block_k, hkv, d)
-    scale = 1.0 / jnp.sqrt(d)
+    scale = 1.0 / jnp.sqrt(d) if scale is None else scale
 
     kpos_all = jnp.arange(nk * block_k).reshape(nk, block_k)
     valid_k = kpos_all < lk
@@ -104,10 +109,11 @@ def flash_attention(
             m2, l2, o2 = _block_attend(qb, kb, vb, mask, scale)
             return _merge(m, l, o, m2, l2, o2), None
 
+        step = jax.checkpoint(kv_step) if remat_tiles else kv_step
         m0 = jnp.full((b, hkv, g, block_q), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, hkv, g, block_q), jnp.float32)
         o0 = jnp.zeros((b, hkv, g, block_q, d), jnp.float32)
-        (m, l, o), _ = jax.lax.scan(kv_step, (m0, l0, o0), (ks.transpose(1, 0, 2, 3, 4), vs.transpose(1, 0, 2, 3, 4), kpos_all, valid_k))
+        (m, l, o), _ = jax.lax.scan(step, (m0, l0, o0), (ks.transpose(1, 0, 2, 3, 4), vs.transpose(1, 0, 2, 3, 4), kpos_all, valid_k))
         out = o / jnp.maximum(l, 1e-30)[..., None]
         return out  # (B,Hk,G,BQ,D)
 

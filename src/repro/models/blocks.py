@@ -18,6 +18,7 @@ from repro.models.layers import (
     apply_mlp,
     dense_init,
     init_mlp,
+    linear,
     maybe_shard_axis,
     rms_norm,
     rope,
@@ -45,9 +46,9 @@ def init_attn(key, cfg):
 def _qkv(p, cfg, x, positions, *, head_local: bool = False):
     b, l, _ = x.shape
     hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
-    q = (x @ p["wq"]).reshape(b, l, hq, hd)
-    k = (x @ p["wk"]).reshape(b, l, hkv, hd)
-    v = (x @ p["wv"]).reshape(b, l, hkv, hd)
+    q = linear(x, p["wq"]).reshape(b, l, hq, hd)
+    k = linear(x, p["wk"]).reshape(b, l, hkv, hd)
+    v = linear(x, p["wv"]).reshape(b, l, hkv, hd)
     if head_local:
         # §Perf lever (activation_sharding): repeat kv to full q heads
         # (GQA == repeated-kv MHA) and pin every tensor head-sharded over
@@ -104,7 +105,7 @@ def apply_attn(p, cfg, x, *, positions, use_window: bool = False):
             parallel_q=cfg.seq_par_attention,
         )
     b, l, _ = x.shape
-    return out.reshape(b, l, -1) @ p["wo"]
+    return linear(out.reshape(b, l, -1), p["wo"])
 
 
 def prefill_attn(p, cfg, x, *, positions, cache_size: int, use_window: bool):
@@ -131,7 +132,7 @@ def prefill_attn(p, cfg, x, *, positions, cache_size: int, use_window: bool):
         pad = cache_size - l
         k_cache = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v_cache = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    attn_out = out.reshape(b, l, -1) @ p["wo"]
+    attn_out = linear(out.reshape(b, l, -1), p["wo"])
     return attn_out, (k_cache, v_cache)
 
 
@@ -139,9 +140,9 @@ def decode_attn(p, cfg, x1, cache_kv, pos, *, ring: bool):
     """x1: (B, d); cache_kv = (k_cache, v_cache) (B, S, Hkv, D); pos (B,)."""
     b = x1.shape[0]
     hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
-    q = (x1 @ p["wq"]).reshape(b, 1, hq, hd)
-    k = (x1 @ p["wk"]).reshape(b, 1, hkv, hd)
-    v = (x1 @ p["wv"]).reshape(b, 1, hkv, hd)
+    q = linear(x1, p["wq"]).reshape(b, 1, hq, hd)
+    k = linear(x1, p["wk"]).reshape(b, 1, hkv, hd)
+    v = linear(x1, p["wv"]).reshape(b, 1, hkv, hd)
     q = rope(q, pos[:, None], cfg.rope_theta)[:, 0]
     k = rope(k, pos[:, None], cfg.rope_theta)[:, 0]
     v = v[:, 0]
@@ -155,7 +156,7 @@ def decode_attn(p, cfg, x1, cache_kv, pos, *, ring: bool):
         q, k_cache, v_cache, pos + 1,
         window=cfg.sliding_window if not ring else 0, ring=ring,
     )
-    return out.reshape(b, -1) @ p["wo"], (k_cache, v_cache)
+    return linear(out.reshape(b, -1), p["wo"]), (k_cache, v_cache)
 
 
 # ---------------------------------------------------------------------------

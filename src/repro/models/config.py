@@ -39,6 +39,22 @@ class ModelConfig:
     ssm_conv_width: int = 4
     # hybrid (zamba2-style): apply the shared attention block every N layers
     shared_attn_every: int = 0
+    # per-layer pattern (granite-4.0-h): each layer's mixer, "mamba" or
+    # "attention" (NoPE), each followed by the routed experts plus a shared
+    # SwiGLU of width ``shared_d_ff``; tied head (models/pattern.py)
+    layer_types: tuple = ()
+    shared_d_ff: int = 0
+    # contiguous expert ids ``(lo, hi)`` this chip holds of ``num_experts``;
+    # () holds them all.  The router keeps all ``num_experts`` outputs.
+    experts_held: tuple = ()
+    # muP-style multipliers: h0 = embed * m_emb, h += m_res * block(h),
+    # logits = head(h) / logits_scaling; attention scores * attention_multiplier
+    # (0: 1/sqrt(head_dim))
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: float = 0.0
+    norm_eps: float = 1e-6
     # modality frontend stub: "none" (tokens) | "patch" (VLM) | "frame" (audio)
     frontend: str = "none"
     frontend_dim: int = 0   # embedding dim delivered by the stubbed frontend
@@ -87,6 +103,10 @@ class ModelConfig:
     @property
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
+
+    @property
+    def held_range(self) -> tuple:
+        return tuple(self.experts_held) or (0, self.num_experts)
 
     @property
     def pdtype(self):
@@ -149,6 +169,12 @@ def validate(cfg: ModelConfig) -> None:
         assert cfg.d_inner % cfg.ssm_head_dim == 0
     if cfg.family == "moe":
         assert 0 < cfg.top_k <= cfg.num_experts
+    if cfg.layer_types:
+        assert len(cfg.layer_types) == cfg.num_layers
+        assert set(cfg.layer_types) <= {"mamba", "attention"}
+        assert cfg.num_heads % cfg.num_kv_heads == 0
+        lo, hi = cfg.held_range
+        assert 0 <= lo < hi <= cfg.num_experts and 0 < cfg.top_k <= cfg.num_experts
     if cfg.family == "vlm":
         assert cfg.frontend == "patch" and cfg.prefix_len > 0
     if cfg.family == "audio":

@@ -61,6 +61,16 @@ def dense_init(key, shape, dtype, scale: float | None = None):
     return (s * jax.random.truncated_normal(key, -2.0, 2.0, shape)).astype(dtype)
 
 
+def linear(x, w):
+    """``x @ w``, or for an adapted weight ``{"w": W, "a": A, "b": B}`` (a
+    LoRA site with its scaling folded into A; ``fed/workload.attach_lora``)
+    the unmerged ``x @ W + (x @ A) @ B``: the frozen W is shared by every
+    client, only the rank-r path is per client."""
+    if isinstance(w, dict):
+        return x @ w["w"] + ((x @ w["a"]) @ w["b"]).astype(x.dtype)
+    return x @ w
+
+
 def rms_norm(x, weight, eps: float = 1e-6):
     dt = x.dtype
     x = x.astype(jnp.float32)

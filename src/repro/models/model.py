@@ -58,6 +58,10 @@ def _hybrid_segments(cfg: ModelConfig):
 
 def build_model(cfg: ModelConfig) -> Model:
     validate(cfg)
+    if cfg.layer_types:
+        from repro.models.pattern import build_pattern_model
+
+        return build_pattern_model(cfg)
     L = cfg.num_layers
     is_hybrid = cfg.family == "hybrid" and cfg.shared_attn_every > 0
     attn_cfg = cfg.with_(family="dense") if is_hybrid else cfg  # shared block = attention

@@ -86,3 +86,49 @@ def apply_moe(p, x, *, num_experts: int, top_k: int, capacity_factor: float, act
     lb = e * jnp.sum(me * ce)
     z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
     return y.astype(x.dtype), (lb, z)
+
+
+# ---------------------------------------------------------------------------
+# expert share: one chip's contiguous range of experts, no capacity, no drop
+# ---------------------------------------------------------------------------
+
+
+def init_moe_share(key, cfg):
+    """Router over all ``cfg.num_experts`` (kept at its published width) and
+    SwiGLU experts for the ids ``cfg.held_range`` only."""
+    lo, hi = cfg.held_range
+    k_router, k_experts = jax.random.split(key)
+    p = init_moe(k_experts, cfg.d_model, cfg.d_ff, hi - lo, "swiglu", cfg.pdtype)
+    p["router"] = dense_init(k_router, (cfg.d_model, cfg.num_experts), cfg.pdtype)
+    return p
+
+
+def apply_moe_share(p, x, *, top_k: int, held: tuple):
+    """This chip's part of a top-k MoE (granite-4.0-h's
+    ``GraniteMoeHybridTopKGating``: the top-k router logits, then a softmax
+    over those k).  Every token is routed over all experts; the experts
+    ``held = (lo, hi)`` compute their part for every token routed to them,
+    weighted by its gate, and what absent experts would add is left out.
+    The router's logits are float32 accumulations (bf16 operands in a bf16
+    model), so near ties are not decided by a rounding of the logits.
+
+    Dense over the held experts: each computes every token and the gate
+    (zero where a token did not choose it) selects, so no token is dropped
+    and the shape is static under the client vmap and autodiff.  That is
+    ``(hi - lo) / (top_k * (hi - lo) / num_experts)`` times the routed
+    expert FLOPs (7.2x for 9 of 72 experts at top-10).
+
+    x: (..., d) -> (y, experts): ``experts`` (..., top_k) int32, the ids of
+    the experts each token chose, held or not."""
+    lo, hi = held
+    logits = jnp.einsum("...d,de->...e", x, p["router"], preferred_element_type=jnp.float32)
+    vals, idx = jax.lax.top_k(logits, top_k)
+    gates = jax.nn.softmax(vals, axis=-1)
+    # one_hot of an id outside [0, hi - lo) is a zero row: absent experts
+    routed = jax.nn.one_hot(idx - lo, hi - lo, dtype=jnp.float32)  # (..., k, n)
+    combine = jnp.einsum("...k,...kn->...n", gates, routed)
+    h = jax.nn.silu(jnp.einsum("...d,edf->...ef", x, p["gate"])) * jnp.einsum(
+        "...d,edf->...ef", x, p["up"])
+    h = h * combine[..., None].astype(h.dtype)
+    y = jnp.einsum("...ef,efd->...d", h, p["down"])
+    return y.astype(x.dtype), idx

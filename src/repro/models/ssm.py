@@ -19,7 +19,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import dense_init
+from repro.models.layers import dense_init, linear
 
 
 def init_mamba2(key, cfg):
@@ -46,8 +46,23 @@ def _causal_conv(xbc, w, b):
     return jax.nn.silu(out + b[None, None, :])
 
 
-def _ssd_chunked(x, dt, A, B, C, D, chunk: int):
-    """Chunked SSD scan.  Returns (y, final_state)."""
+def _intra_chunk(a_cs, C, B, xbar):
+    """Within a chunk, ``y_i = sum_{j<=i} (C_i . B_j) exp(a_cs_i - a_cs_j)
+    xbar_j``; leading axes are batch (and chunk) axes."""
+    seg = a_cs[..., :, None, :] - a_cs[..., None, :, :]  # (..., i, j, h)
+    iq = jnp.arange(a_cs.shape[-2])
+    causal = (iq[:, None] >= iq[None, :])[(None,) * (a_cs.ndim - 2) + (..., None)]
+    # mask BEFORE exp: exp of the (positive, growing) anti-causal entries
+    # would overflow and poison gradients through the where
+    Lmat = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+    scores = jnp.einsum("...in,...jn->...ij", C.astype(jnp.float32), B.astype(jnp.float32))
+    return jnp.einsum("...ij,...ijh,...jhp->...ihp", scores, Lmat, xbar)
+
+
+def _ssd_chunked(x, dt, A, B, C, D, chunk: int, chunk_remat: bool = False):
+    """Chunked SSD scan.  Returns (y, final_state).  ``chunk_remat`` computes
+    the intra-chunk term one checkpointed chunk at a time (a sequential map),
+    so training holds one chunk's quadratic tensors instead of all of them."""
     b, l, h, p = x.shape
     n = B.shape[-1]
     pad = (-l) % chunk
@@ -68,16 +83,16 @@ def _ssd_chunked(x, dt, A, B, C, D, chunk: int):
 
     xbar = xc.astype(jnp.float32) * dtc[..., None]
 
-    # intra-chunk (quadratic in the chunk — MXU-friendly):
-    # L[i,j] = exp(a_cs_i - a_cs_j) for i >= j
-    seg = a_cs[:, :, :, None, :] - a_cs[:, :, None, :, :]  # (b,nc,i,j,h)
-    iq = jnp.arange(chunk)
-    causal = (iq[:, None] >= iq[None, :])[None, None, :, :, None]
-    # mask BEFORE exp: exp of the (positive, growing) anti-causal entries
-    # would overflow and poison gradients through the where
-    Lmat = jnp.exp(jnp.where(causal, seg, -jnp.inf))
-    scores = jnp.einsum("bcin,bcjn->bcij", Cc.astype(jnp.float32), Bc.astype(jnp.float32))
-    y_intra = jnp.einsum("bcij,bcijh,bcjhp->bcihp", scores, Lmat, xbar)
+    # intra-chunk (quadratic in the chunk — MXU-friendly)
+    if chunk_remat:
+        # one chunk at a time, each checkpointed: only a single chunk's
+        # (b, i, j, h) decay and score tensors are live, backward as forward
+        intra = jax.checkpoint(lambda args: _intra_chunk(*args))
+        chunk_major = lambda t: jnp.moveaxis(t, 1, 0)
+        y_intra = jnp.moveaxis(jax.lax.map(
+            intra, (chunk_major(a_cs), chunk_major(Cc), chunk_major(Bc), chunk_major(xbar))), 0, 1)
+    else:
+        y_intra = _intra_chunk(a_cs, Cc, Bc, xbar)
 
     # chunk-state contributions: S_c = sum_j exp(a_tot - a_cs_j) * B_j x_j^T
     w_in = jnp.exp(a_tot[:, :, None, :] - a_cs)  # (b,nc,j,h)
@@ -109,10 +124,11 @@ def _split_proj(cfg, proj):
     return z, xbc, dt
 
 
-def apply_mamba2(p, cfg, u, *, return_state: bool = False):
-    """u: (B, L, d_model) -> (B, L, d_model)."""
+def apply_mamba2(p, cfg, u, *, return_state: bool = False, chunk_remat: bool = False):
+    """u: (B, L, d_model) -> (B, L, d_model).  ``chunk_remat``: see
+    :func:`_ssd_chunked`."""
     di, n, h = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
-    proj = u @ p["in_proj"]
+    proj = linear(u, p["in_proj"])
     z, xbc_raw, dt_raw = _split_proj(cfg, proj)
     xbc = _causal_conv(xbc_raw, p["conv_w"], p["conv_b"])
     x, B, C = jnp.split(xbc, [di, di + n], axis=-1)
@@ -125,13 +141,13 @@ def apply_mamba2(p, cfg, u, *, return_state: bool = False):
         from repro.models.layers import maybe_shard_axis
 
         xh = maybe_shard_axis(xh, 2)
-    y, state = _ssd_chunked(xh, dt, A, B, C, p["D"], cfg.ssm_chunk)
+    y, state = _ssd_chunked(xh, dt, A, B, C, p["D"], cfg.ssm_chunk, chunk_remat)
     y = y.reshape(*u.shape[:2], di)
     # gated RMSNorm (mamba2)
     g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
     var = jnp.mean(g * g, axis=-1, keepdims=True)
-    g = g * jax.lax.rsqrt(var + 1e-6) * (1.0 + p["gate_norm_w"].astype(jnp.float32))
-    out = g.astype(u.dtype) @ p["out_proj"]
+    g = g * jax.lax.rsqrt(var + cfg.norm_eps) * (1.0 + p["gate_norm_w"].astype(jnp.float32))
+    out = linear(g.astype(u.dtype), p["out_proj"])
     if return_state:
         cw = cfg.ssm_conv_width
         # cache keeps the *raw* (pre-conv) xbc tail, matching decode_mamba2
@@ -156,7 +172,7 @@ def init_ssm_cache(cfg, batch: int, dtype=jnp.float32):
 def decode_mamba2(p, cfg, u1, cache):
     """Single-token step.  u1: (B, d_model)."""
     di, n, h = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
-    proj = u1 @ p["in_proj"]
+    proj = linear(u1, p["in_proj"])
     z, xbc_new, dt_raw = _split_proj(cfg, proj)
     # depthwise conv over ring buffer + current input
     window = jnp.concatenate([cache["conv"], xbc_new[:, None, :]], axis=1)  # (B,cw,D)
@@ -174,8 +190,8 @@ def decode_mamba2(p, cfg, u1, cache):
     y = y.reshape(-1, di)
     g = y * jax.nn.silu(z.astype(jnp.float32))
     var = jnp.mean(g * g, axis=-1, keepdims=True)
-    g = g * jax.lax.rsqrt(var + 1e-6) * (1.0 + p["gate_norm_w"].astype(jnp.float32))
-    out = g.astype(u1.dtype) @ p["out_proj"]
+    g = g * jax.lax.rsqrt(var + cfg.norm_eps) * (1.0 + p["gate_norm_w"].astype(jnp.float32))
+    out = linear(g.astype(u1.dtype), p["out_proj"])
     new_cache = {
         "state": state,
         "conv": jnp.concatenate([cache["conv"][:, 1:], xbc_new[:, None, :]], axis=1),
