@@ -29,7 +29,9 @@ ServerState)`` as carry, minibatch indices drawn *on device* with
 ``server_step`` (reputation + blocking) inlined into the scan body, and the
 per-round test error emitted as a scan output.  Host↔device syncs drop from
 O(T) to O(1), and a whole simulation becomes a vmappable value — ``run_sweep``
-maps it over a seed axis in a single device program.
+maps it over a seed axis in a single device program.  Its local update runs
+over the rows that can train (``train_rows``, :func:`trainer_count`), not
+over the byzantine rows whose result the attack overwrites.
 
 The **segmented** form (``make_fused_segment``) is the same scan cut into
 segments of S rounds so the host can *compact* blocked clients out of the
@@ -68,7 +70,11 @@ import numpy as np
 
 from repro.attacks import UPDATE_ATTACK_SCENARIOS, apply_update_attack
 from repro.utils.spans import span
-from repro.utils.trees import tree_broadcast_clients, tree_select_rows
+from repro.utils.trees import (
+    tree_broadcast_clients,
+    tree_scatter_rows,
+    tree_select_rows,
+)
 
 # scenarios whose proposal transform touches only its own client row — these
 # run client-sharded with no cross-shard communication at the attack layer
@@ -132,7 +138,7 @@ def attack_key(seed: int, rnd: int) -> jnp.ndarray:
 
 def _train_and_attack(
     workload, cfg: EngineConfig, params, batch, keys, train_mask, bad_mask,
-    benign_mask, akey, client_ids=None, client_axis=None,
+    benign_mask, akey, client_ids=None, client_axis=None, rows=None,
 ):
     """The shared proposal pipeline: vmapped local training over the stacked
     client axis, non-trainer rows reset to the current proposal-space point
@@ -141,10 +147,13 @@ def _train_and_attack(
     engines cannot drift apart.  ``client_ids`` maps rows to original client
     ids under compaction (None = identity layout); ``client_axis`` names the
     mesh axis when the stack is client-sharded (alie/ipm psum their benign
-    moments over it).  Returns ``(proposals, stats)``: ``stats`` are the
-    workload's per-client local-training statistics
-    (``local_update_with_stats``, leading axis K), zero in the rows that did
-    not train; None for a workload that reports none."""
+    moments over it).  ``rows`` is the trainer list of
+    :func:`_trainer_rows`: ``batch`` and ``keys`` then carry its rows only,
+    and their results are scattered into the K-row stack (None: every row
+    trains and non-trainers are selected back).  Returns ``(proposals,
+    stats)``: ``stats`` are the workload's per-client local-training
+    statistics (``local_update_with_stats``, leading axis K), zero in the
+    rows that did not train; None for a workload that reports none."""
     K = train_mask.shape[0]
     # the reference point attacks perturb and non-trainers hold: the current
     # params projected to proposal space (identity for full-param workloads,
@@ -156,14 +165,24 @@ def _train_and_attack(
 
     with jax.named_scope("local_update"):
         proposals, stats = jax.vmap(train_one)(batch, keys)
-        # non-trainers hold w_t until the attack layer overwrites their row
-        proposals = tree_select_rows(
-            train_mask, proposals, tree_broadcast_clients(w_prev, K)
-        )
-        # the workload's statistics, zero where a client did not train
-        stats = jax.tree_util.tree_map(
-            lambda s: jnp.where(train_mask.reshape((K,) + (1,) * (s.ndim - 1)), s, 0),
-            stats)
+        if rows is None:
+            # non-trainers hold w_t until the attack layer overwrites their row
+            proposals = tree_select_rows(
+                train_mask, proposals, tree_broadcast_clients(w_prev, K)
+            )
+            # the workload's statistics, zero where a client did not train
+            stats = jax.tree_util.tree_map(
+                lambda s: jnp.where(train_mask.reshape((K,) + (1,) * (s.ndim - 1)), s, 0),
+                stats)
+        else:
+            # the trainers' rows into the stack: every other row holds w_t
+            # and zero statistics, as the select above gives
+            proposals = tree_scatter_rows(
+                tree_broadcast_clients(w_prev, K), rows, proposals)
+            stats = tree_scatter_rows(
+                jax.tree_util.tree_map(
+                    lambda s: jnp.zeros((K,) + s.shape[1:], s.dtype), stats),
+                rows, stats)
     with jax.named_scope("attack"):
         return apply_update_attack(
             cfg.scenario, proposals, w_prev, bad_mask, benign_mask, akey,
@@ -228,9 +247,12 @@ class FusedTrajectory(NamedTuple):
     workload_stats: Any = None
 
 
-def _gather_rows(stack, idx):
+def _gather_rows(stack, idx, rows=None):
     """``stack[k, idx[k]]`` for every client row ``k``: the device minibatch
-    draw from a ``(K, n_max, ...)`` shard stack.
+    draw from a ``(K, n_max, ...)`` shard stack.  With ``rows`` (``(H,)``
+    row indices, ``idx`` then ``(H, ...)``) it is ``stack[rows[h],
+    idx[h]]``: the row index folds into the one gather, and no ``(H, n_max,
+    ...)`` copy of the stack is made.
 
     f32 rows move as raw 32-bit words.  Gathering the floats lets the TPU
     compiler narrow the whole stack to bf16 ahead of the gather (the local
@@ -239,29 +261,71 @@ def _gather_rows(stack, idx):
     bits is exact, so the draw is unchanged on every backend."""
     if stack.dtype == jnp.float32:
         words = jax.lax.bitcast_convert_type(stack, jnp.uint32)
-        return jax.lax.bitcast_convert_type(_gather_rows(words, idx), jnp.float32)
-    return jax.vmap(lambda xs, ix: xs[ix])(stack, idx)
+        return jax.lax.bitcast_convert_type(
+            _gather_rows(words, idx, rows), jnp.float32)
+    if rows is None:
+        return jax.vmap(lambda xs, ix: xs[ix])(stack, idx)
+    return stack[rows.reshape(rows.shape + (1,) * (idx.ndim - 1)), idx]
+
+
+def trainer_count(scenario: str, bad, live=None) -> int:
+    """How many rows of a client layout can train: its client rows
+    (``live``, a bool mask of the rows that are not padding; None = every
+    row) less, under an update-level attack, the byzantine ones, whose
+    forged rows never train (``flipping`` and ``noisy`` clients train on
+    poisoned data, so they count).  Blocking is monotone, so no round of the
+    layout has more trainers: the engines run the local update over this
+    many rows (``train_rows``)."""
+    can = np.ones(len(bad), bool) if live is None else np.asarray(live, bool)
+    if scenario in UPDATE_ATTACK_SCENARIOS:
+        can = can & ~np.asarray(bad, bool)
+    return int(can.sum())
+
+
+def _trainer_rows(train_mask, train_rows):
+    """The rows the local update runs over: the ``train_rows`` row indices
+    of ``train_mask``'s trainers, ascending, then fill slots holding the row
+    count, which compute on the last row and which the scatter back drops
+    (a client blocked inside a segment leaves one).  None where every row of
+    the stack trains (``train_rows`` None or the row count): the full-row
+    program."""
+    K = train_mask.shape[0]
+    if train_rows is None or train_rows >= K:
+        return None
+    return jnp.nonzero(train_mask, size=max(train_rows, 1), fill_value=K)[0]
 
 
 def _propose_round(
     workload, cfg: EngineConfig, num_clients_total, batch_s, batch_b,
     client_axis, params, blocked, rnd, seed, data: FusedData, bad, client_ids,
+    train_rows=None,
 ):
     """One round's PROPOSAL phase, factored out of :func:`_round_body` so the
     serving tier (``repro.serve``) traces the IDENTICAL op sequence when it
     computes client submissions outside the fused scan: participation masks,
     the device minibatch draw, vmapped local training, and the update-level
-    attack — everything up to (but not including) aggregation.  Returns
-    ``(proposals, mask0, stats)`` with ``proposals`` a stacked proposal-space
-    pytree, ``mask0`` the live-participant mask and ``stats`` the workload's
-    per-client local-training statistics (see :func:`_train_and_attack`)."""
+    attack — everything up to (but not including) aggregation.  With
+    ``train_rows`` (static; at least the trainers of any round, see
+    :func:`trainer_count`) the draw, the gather and the local update run
+    over that many trainer rows only (:func:`_trainer_rows`); keys and
+    draws stay keyed by original client id, so each trained row is the same
+    computation.  Returns ``(proposals, mask0, stats)`` with ``proposals`` a
+    stacked proposal-space pytree, ``mask0`` the live-participant mask and
+    ``stats`` the workload's per-client local-training statistics (see
+    :func:`_train_and_attack`)."""
     skip_bad = cfg.scenario in UPDATE_ATTACK_SCENARIOS
     mask0 = ~blocked
     train_mask = mask0 & ~bad if skip_bad else mask0
+    rows = _trainer_rows(train_mask, train_rows)
 
     base = jax.random.PRNGKey(seed)
     ids = jnp.asarray(client_ids, jnp.uint32)
-    offsets = jnp.asarray(rnd).astype(jnp.uint32) * jnp.uint32(num_clients_total) + ids
+    # the local update's rows and their ids and lengths: every row, or the
+    # trainer list with its fill slots clamped onto the last row
+    take = None if rows is None else jnp.minimum(rows, ids.shape[0] - 1)
+    tids = ids if take is None else ids[take]
+    lengths = data.lengths if take is None else data.lengths[take]
+    offsets = jnp.asarray(rnd).astype(jnp.uint32) * jnp.uint32(num_clients_total) + tids
 
     # device-side minibatch draw: one key per (round, client), per-client
     # maxval — pad rows carry length 1 so the draw range is never empty
@@ -270,15 +334,17 @@ def _propose_round(
         bkeys = jax.vmap(lambda o: jax.random.fold_in(bbase, o))(offsets)
         idx = jax.vmap(
             lambda k, n: jax.random.randint(k, (batch_s, batch_b), 0, n)
-        )(bkeys, data.lengths)
-        batch = {"x": _gather_rows(data.x, idx), "y": _gather_rows(data.y, idx)}
+        )(bkeys, lengths)
+        batch = {"x": _gather_rows(data.x, idx, take),
+                 "y": _gather_rows(data.y, idx, take)}
     proposals, stats = _train_and_attack(
         workload, cfg, params, batch,
-        client_keys_traced(seed, rnd, ids, num_clients_total),
+        client_keys_traced(seed, rnd, tids, num_clients_total),
         train_mask, bad & mask0, mask0 & ~bad,
         jax.random.fold_in(base, rnd),
         client_ids=ids,
         client_axis=client_axis,
+        rows=rows,
     )
     return proposals, mask0, stats
 
@@ -317,7 +383,7 @@ def make_packed_propose_fn(
 def _round_body(
     workload, cfg: EngineConfig, rule, opts, delta_block, agg_layout,
     num_clients_total, batch_s, batch_b, client_axis,
-    carry, rnd, seed, data: FusedData, bad, client_ids,
+    carry, rnd, seed, data: FusedData, bad, client_ids, *, train_rows=None,
 ):
     """ONE fused round, parameterized over a (possibly compacted) client
     layout.  ``bad`` and ``client_ids`` are traced ``(K_rows,)`` arrays so
@@ -325,7 +391,9 @@ def _round_body(
     ``num_clients_total`` is the full experiment K, the stride of the
     per-client RNG streams.  All per-client randomness — minibatch indices,
     dropout keys, byzantine noise — is keyed by ORIGINAL client id, making
-    the round bit-invariant to dropping masked-out rows.
+    the round bit-invariant to dropping masked-out rows.  ``train_rows``
+    (static) runs the local update over that many trainer rows
+    (:func:`_propose_round`); None trains every row.
 
     ``agg_layout`` (static) selects the aggregation representation:
 
@@ -347,6 +415,7 @@ def _round_body(
     proposals, mask0, stats = _propose_round(
         workload, cfg, num_clients_total, batch_s, batch_b, client_axis,
         params, state.reputation.blocked, rnd, seed, data, bad, client_ids,
+        train_rows,
     )
 
     if agg_layout == "packed":
@@ -430,11 +499,14 @@ def make_fused_sim(
       time: the bit-equivalence reference for the scan
       (``tests/test_fused_engine.py``).
 
-    In this one-shot form blocked clients keep their row in every fixed-shape
-    computation (their batches still gather, their ``local_sgd`` still runs)
-    and are excluded only by mask — use the segmented form
-    (:func:`make_fused_segment` via ``SimConfig.segment_rounds``) to compact
-    blocked clients out of the stack between segments (DESIGN.md §2).
+    In this one-shot form every client keeps its row in the stack; the
+    local update (with its minibatch gather) runs over the rows that can
+    train (:func:`trainer_count` of ``bad_mask``: under an update-level
+    attack the byzantine rows, whose forged rows never train, are left out)
+    and a client blocked mid-run leaves a fill slot whose result is dropped.
+    The segmented form (:func:`make_fused_segment` via
+    ``SimConfig.segment_rounds``) compacts blocked clients out of the stack
+    between segments (DESIGN.md §2).  Client-sharded, every row trains.
 
     With ``client_mesh`` (a mesh carrying a ``client`` axis,
     ``launch/mesh.make_client_mesh``) the ENTIRE scan runs under
@@ -510,6 +582,8 @@ def _make_fused_sim_cached(
     body = functools.partial(
         _round_body, workload, cfg, rule, opts, delta_block, agg_layout,
         K, batch_s, batch_b, axis,
+        train_rows=(trainer_count(cfg.scenario, bad_tuple)
+                    if client_mesh is None else None),
     )
 
     codec = workload.codec
@@ -657,6 +731,7 @@ def make_fused_segment(
     agg_layout: str = "packed",
     client_mesh=None,
     bucket_rows: int | None = None,
+    train_rows: int | None = None,
 ):
     """Build one S-round segment of the fused simulation (DESIGN.md §2).
 
@@ -679,6 +754,12 @@ def make_fused_segment(
     single ``(K_bucket, D)`` packed buffer, so compaction's effect on the
     aggregation hot path is exactly a row-count change of one matrix.
 
+    ``train_rows`` is the layout's :func:`trainer_count`: the local update
+    runs over that many trainer rows and its results are scattered back
+    into the bucket (None: every row trains).  The caller passes it with
+    the layout it stages; it keys the cache like the bucket, which it
+    changes with.  The client-sharded segment trains every row.
+
     With ``client_mesh`` the segment runs under ``shard_map`` over the
     client axis like :func:`make_fused_sim`; the caller compacts PER SHARD
     (``data/sharding.shard_compact_plan``): every shard holds
@@ -700,6 +781,7 @@ def make_fused_segment(
         workload, cfg, rule, opts, float(delta_block),
         int(num_clients_total), int(seg_len), int(batch_s), int(batch_b),
         agg_layout, client_mesh,
+        None if train_rows is None or client_mesh is not None else int(train_rows),
     )
 
 
@@ -727,10 +809,12 @@ def _attack_axis(client_mesh) -> str | None:
 def _make_fused_segment_cached(
     workload, cfg: EngineConfig, rule, opts, delta_block,
     num_clients_total, seg_len, batch_s, batch_b, agg_layout, client_mesh=None,
+    train_rows=None,
 ):
     body = functools.partial(
         _round_body, workload, cfg, rule, opts, delta_block, agg_layout,
         num_clients_total, batch_s, batch_b, _attack_axis(client_mesh),
+        train_rows=train_rows,
     )
 
     def _scan(params, state, seed, data, bad, client_ids, seg_start):

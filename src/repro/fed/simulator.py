@@ -87,6 +87,7 @@ from repro.fed.engine import (
     make_train_attack_step,
     place_on_client_mesh,
     sweep_fused_sim,
+    trainer_count,
 )
 from repro.fed.server import (
     FedServer,
@@ -757,9 +758,11 @@ def _compact_inputs(setup: _Setup, kept: np.ndarray, bucket: int, mesh=None,
 
 
 def _segment_fn(setup: _Setup, server_cfg: ServerConfig, seg_len: int,
-                mesh=None, bucket_rows: int | None = None):
+                mesh=None, bucket_rows: int | None = None,
+                train_rows: int | None = None):
     """Segment scan for this experiment's static configuration (cached in
-    ``make_fused_segment`` — one trace per (bucket shape, seg_len))."""
+    ``make_fused_segment`` — one trace per (bucket shape, seg_len,
+    train_rows))."""
     sim = setup.sim
     return make_fused_segment(
         setup.workload, setup.engine_config(),
@@ -775,7 +778,19 @@ def _segment_fn(setup: _Setup, server_cfg: ServerConfig, seg_len: int,
         agg_layout=resolve_server_plan(server_cfg).layout,
         client_mesh=mesh,
         bucket_rows=bucket_rows,
+        train_rows=train_rows,
     )
+
+
+def _train_rows(setup: _Setup, kept: np.ndarray, bucket: int, mesh) -> int:
+    """How many rows of the ``(kept, bucket)`` layout the local update runs
+    over: its clients that can train (:func:`~repro.fed.engine.trainer_count`;
+    pad slots never do).  Client-sharded, every row of the bucket trains."""
+    if mesh is not None:
+        return bucket
+    live = kept >= 0
+    return trainer_count(setup.sim.scenario,
+                         setup.bad_mask[np.where(live, kept, 0)], live)
 
 
 def _segment_layout(live: np.ndarray, K: int, n_shards: int, mesh):
@@ -830,6 +845,7 @@ def first_segment(
     seg_fn = _segment_fn(
         setup, server_cfg, min(sim.segment_rounds, sim.rounds), mesh,
         None if mesh is None else bucket // n_shards,
+        _train_rows(setup, kept, bucket, mesh),
     )
     return seg_fn, (
         params, state_c, jnp.uint32(sim.seed), data_c, bad_c, ids_c,
@@ -885,7 +901,7 @@ def _run_fused_segmented(
     state_c = state_full
     data_c, bad_c, ids_c = None, None, None
     kept = np.arange(K)
-    bucket = None
+    bucket = train_rows = None
 
     seg_start = 0
     while seg_start < T:
@@ -911,12 +927,14 @@ def _run_fused_segmented(
                     params, state_c, data_c, bad_c, ids_c = _segment_inputs(
                         setup, params, state_full, kept, bucket, mesh, stage
                     )
-                    stage["rows"] = int((kept >= 0).sum())
+                    train_rows = _train_rows(setup, kept, bucket, mesh)
+                    stage.update(rows=int((kept >= 0).sum()),
+                                 train_rows=train_rows)
             seg.update(bucket=int(bucket), live=len(live))
             with span("fed.segment.call"):
                 seg_fn = _segment_fn(
                     setup, server_cfg, seg_len, mesh,
-                    None if mesh is None else bucket // n_shards,
+                    None if mesh is None else bucket // n_shards, train_rows,
                 )
                 params, state_c, traj = seg_fn(
                     params, state_c, seed, data_c, bad_c, ids_c,
@@ -1081,7 +1099,8 @@ def _run_sweep_segmented(
             bucket, kept = new_bucket, live
             data_c, bad_c, ids_c = _compact_inputs(setup, kept, bucket)
             state_c = gather_server_state(state_full, kept, bucket)
-        seg_fn = _segment_fn(setup, server_cfg, seg_len)
+        seg_fn = _segment_fn(setup, server_cfg, seg_len,
+                             train_rows=_train_rows(setup, kept, bucket, None))
         params, state_c, traj = jax.vmap(
             seg_fn, in_axes=(0, 0, 0, None, None, None, None)
         )(params, state_c, seeds_u32, data_c, bad_c, ids_c, jnp.int32(seg_start))

@@ -584,12 +584,18 @@ def simulate_llm(
     every layer) and ``trained`` ``(T, K)`` (which rows they belong to).
 
     Spans: ``fed.setup`` (data to the device, ``h2d_bytes``; the model's
-    init where no ``params0`` is given), ``fed.llm.call`` (the scan's dispatch), ``fed.llm.wait`` (until its
-    trajectory is ready), ``fed.result``; and, for a workload whose model
-    reports routing, one ``fed.moe.route`` record (see
-    :func:`_record_route`).
+    init where no ``params0`` is given), ``fed.llm.call`` (the scan's
+    dispatch; ``rows``, the clients, and ``train_rows``, the rows the local
+    update runs over), ``fed.llm.wait`` (until its trajectory is ready),
+    ``fed.result``; and, for a workload whose model reports routing, one
+    ``fed.moe.route`` record (see :func:`_record_route`).
     """
-    from repro.fed.engine import EngineConfig, FusedData, make_fused_sim
+    from repro.fed.engine import (
+        EngineConfig,
+        FusedData,
+        make_fused_sim,
+        trainer_count,
+    )
     from repro.fed.server import ServerConfig, make_rule_options
 
     with span("fed.setup") as attrs:
@@ -622,7 +628,8 @@ def simulate_llm(
         )
         if params0 is None:
             params0 = _init_fn(workload)(jax.random.PRNGKey(seed))
-    with span("fed.llm.call"):
+    with span("fed.llm.call", rows=clients,
+              train_rows=trainer_count(scenario, bad)):
         params, state, traj, *first = scan_fn(params0, np.uint32(seed), data)
     with span("fed.llm.wait"):
         jax.block_until_ready(traj)

@@ -91,6 +91,14 @@ def tree_select_rows(mask, a, b):
     )
 
 
+def tree_scatter_rows(base, rows, tree):
+    """``base`` with row ``rows[h]`` of its leading client axis set to row
+    ``h`` of ``tree``, leafwise; an index past the last row is dropped."""
+    return jax.tree_util.tree_map(
+        lambda lb, lt: lb.at[rows].set(lt, mode="drop"), base, tree
+    )
+
+
 def tree_zeros_like(a, dtype=None):
     return jax.tree_util.tree_map(
         lambda x: jnp.zeros(x.shape, dtype or x.dtype), a

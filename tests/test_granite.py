@@ -287,6 +287,18 @@ def test_llm_route_spans_and_route_record(round1):
     assert 0 < route["held_share"] < 1 and route["max_over_mean"] >= 1
 
 
+def test_llm_route_trains_the_honest_rows_and_keeps_k_rows_of_stats(round1):
+    """The byzantine rows do not train: ``fed.llm.call`` records K rows and
+    K - BAD trained, and the stats come back in K rows, zero in the rows
+    that did not train."""
+    _, out, recs, _ = round1
+    call = next(r for r in recs if r.name == "fed.llm.call")
+    assert (call.attrs["rows"], call.attrs["train_rows"]) == (K, K - BAD)
+    assert out["experts"].shape[:2] == out["trained"].shape == (1, K)
+    assert not out["experts"][~out["trained"]].any()
+    assert out["experts"][out["trained"]].any()
+
+
 # ---------------------------------------------------------------------------
 # against the upstream model
 # ---------------------------------------------------------------------------

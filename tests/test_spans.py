@@ -92,6 +92,35 @@ def test_segmented_run_span_tree(data):
     assert pools == ["upload", "hit"]
 
 
+def test_stage_records_the_rows_that_train():
+    """K = 100, 30% byzantine: the first layout stages 100 rows, of which
+    the 70 honest ones train."""
+    data = make_mnist_like(n_train=2000, n_test=50, dim=16)
+    sim = _sim(num_clients=100, bad_frac=0.3, rounds=4, batch_size=10,
+               hidden=(8,))
+    _, recs = _run_records(data, sim)
+    (stage,) = [r for r in recs if r.name == "fed.segment.stage"]
+    assert (stage.attrs["bucket"], stage.attrs["rows"], stage.attrs["train_rows"]) \
+        == (100, 100, 70)
+
+
+def test_llm_call_records_the_rows_that_train():
+    """The LLM route with 5 byzantine clients of 16: 11 rows train."""
+    from repro.fed.workload import get_workload, simulate_llm
+    from repro.models import ModelConfig
+
+    cfg = ModelConfig(name="t-lora", family="dense", num_layers=1, d_model=16,
+                      vocab_size=32, num_heads=2, num_kv_heads=1, d_ff=32,
+                      block_q=8, block_k=8)
+    n0 = max((r.span_id for r in spans.records()), default=0)
+    simulate_llm(get_workload("lora", model_cfg=cfg, rank=2), clients=16,
+                 byzantine=5, rounds=1, local_steps=1, batch=1,
+                 samples_per_client=2, seq=8, n_test=2)
+    (call,) = [r for r in spans.records()
+               if r.span_id > n0 and r.name == "fed.llm.call"]
+    assert (call.attrs["rows"], call.attrs["train_rows"]) == (16, 11)
+
+
 def test_ring_stays_bounded():
     for i in range(spans.RING_SIZE + 10):
         with spans.span("ring", i=i):
