@@ -13,7 +13,8 @@ from __future__ import annotations
 import numpy as np
 
 from repro.data import dirichlet_shards, make_mnist_like
-from repro.fed import ServerConfig, SimConfig, run
+from repro.fed import ServerConfig, SimConfig
+from repro.fed import run as fed_run
 
 
 def run(quick: bool = False) -> list[dict]:
@@ -26,7 +27,7 @@ def run(quick: bool = False) -> list[dict]:
         sim = SimConfig(num_clients=10, scenario="byzantine", rounds=rounds,
                         local_epochs=2, batch_size=200, hidden=(512, 256),
                         dropout=False, seed=0)
-        res = run(
+        res = fed_run(
             None, sim,
             ServerConfig(rule="afa", num_clients=10, xi0=xi0),
             data=data,
@@ -52,7 +53,7 @@ def run(quick: bool = False) -> list[dict]:
                         local_epochs=2, batch_size=200, hidden=(512, 256),
                         dropout=False, seed=0,
                         sharding="dirichlet", dirichlet_alpha=alpha)
-        res = run(None, sim, ServerConfig(rule="afa", num_clients=10), data=data)
+        res = fed_run(None, sim, ServerConfig(rule="afa", num_clients=10), data=data)
         shards = dirichlet_shards(data.x_train, data.y_train, 10, alpha=alpha, seed=0)
         sizes = np.asarray([len(x) for x, _ in shards], np.float32)
         rows.append({

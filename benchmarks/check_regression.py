@@ -14,8 +14,8 @@ speedups — each set below the range observed across repeated local ``--tiny``
 runs (see its "note" field) — because a ~1ms microbenchmark's run-to-run
 spread on shared runners can itself approach the tolerance.  The gate's job
 is to catch a layout/dispatch change that erases a speedup class (packed
-dropping to ~1x, fused collapsing toward batched), not to relitigate the
-third significant digit.
+dropping to ~1x, compaction losing its post-blocking win), not to
+relitigate the third significant digit.
 
 Per-scenario thresholds: the ``kernel_*`` scenarios (fused screening kernel
 vs chained launches / jnp oracle) get a wider default tolerance — on CPU CI
@@ -86,8 +86,6 @@ def abs_floor_for(name: str) -> float | None:
 def collect_speedups(doc: dict) -> dict[str, float]:
     """Flatten every speedup scenario of a BENCH_fused_engine.json doc."""
     out: dict[str, float] = {}
-    for r in doc.get("results", []):
-        out[f"fused_vs_batched/K{r['K']}"] = float(r["speedup"])
     for r in doc.get("compaction", []):
         out[f"compaction_post_block/K{r['K']}"] = float(r["post_block_speedup"])
     for r in doc.get("packed", []):

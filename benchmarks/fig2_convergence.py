@@ -7,7 +7,8 @@ from __future__ import annotations
 import os
 
 from repro.data import make_mnist_like
-from repro.fed import ServerConfig, SimConfig, run
+from repro.fed import ServerConfig, SimConfig
+from repro.fed import run as fed_run
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "experiments", "convergence")
 
@@ -23,7 +24,7 @@ def run(quick: bool = False) -> list[dict]:
             sim = SimConfig(num_clients=10, scenario=scenario, rounds=rounds,
                             local_epochs=2, batch_size=200, hidden=(512, 256),
                             dropout=False, seed=0)
-            res = run(None, sim, ServerConfig(rule=rule, num_clients=10), data=data)
+            res = fed_run(None, sim, ServerConfig(rule=rule, num_clients=10), data=data)
             curves[rule] = res.test_error
         path = os.path.join(OUT, f"mnist_like_{scenario}.csv")
         with open(path, "w") as f:

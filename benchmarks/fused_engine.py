@@ -1,10 +1,6 @@
-"""Fused-vs-batched engine comparison plus the segmented-compaction scenario.
-
-The batched engine is one jit per round plus O(T) host work (numpy batch
-draws, reputation sync, Python loop control); the fused engine is ONE jit for
-the whole T-round simulation (`lax.scan`, device-side batch draws, in-scan
-server step).  This benchmark times full simulations under both engines at
-K in {10, 50, 200} and reports per-round wall-clock.
+"""CPU timings of the fused engine's scenarios: segmented compaction, the
+packed layout, the fused screening kernel, the LLM adapter buffer and the
+client-sharded route.
 
 The ``compaction`` scenario exercises the segmented fused engine
 (``SimConfig.segment_rounds`` + ``compact``): K in {50, 200} with 40%
@@ -52,14 +48,13 @@ psum re-associates one summation, so borderline screening verdicts can
 flip at very large K and mask agreement is recorded, not asserted, there).
 
 Emits ``BENCH_fused_engine.json`` at the repo root (machine-readable record
-for the acceptance gates: >= 2x fused-vs-batched at K = 50, >= 1.5x
-post-blocking compaction speedup at K = 200, and >= 1.3x packed-vs-leaf
-aggregation speedup at K = 200, all on CPU) in addition to the usual CSV
-rows.  ``benchmarks/check_regression.py`` gates CI on these speedups against
-the committed ``BENCH_baseline.json``.  ``--tiny`` runs a seconds-scale
-subset for the CI smoke job (including the compaction and packed-layout
-bit-exactness asserts at K = 10; the packed dispatch timing stays at K=200 —
-it involves no training and is cheap).
+for the acceptance gates: >= 1.5x post-blocking compaction speedup at
+K = 200 and >= 1.3x packed-vs-leaf aggregation speedup at K = 200, both on
+CPU) in addition to the usual CSV rows.  ``benchmarks/check_regression.py``
+gates CI on these speedups against the committed ``BENCH_baseline.json``.
+``--tiny`` runs a seconds-scale subset for the CI smoke job (including the
+compaction and packed-layout bit-exactness asserts at K = 10; the packed
+dispatch timing stays at K=200 — it involves no training and is cheap).
 """
 
 from __future__ import annotations
@@ -78,38 +73,13 @@ from repro.kernels.policy import KernelPlan
 
 OUT_JSON = os.path.join(os.path.dirname(__file__), "..", "BENCH_fused_engine.json")
 
-# Small-model workload: the fused engine's target regime (ISSUE/DESIGN §2) —
-# per-round dispatch + host overhead dominates device compute, which is
-# exactly what fusing the T rounds into one scan removes.  At bigger models
-# both engines converge to the same device time (see BENCH_round_engine.json
-# for the model-scale round itself).
+# Small-model workload: per-round dispatch and host overhead dominate device
+# compute, the regime the fused scan and compaction target.
 DIM = 32
 HIDDEN = (16,)
 BATCH = 32
 PER_CLIENT = 100  # samples per shard
 REPEATS = 3
-
-
-def _measure(data, K: int, engine: str, rounds: int) -> float:
-    """Best median per-round wall time (s) over REPEATS timed runs, after a
-    full-length compile warmup.
-
-    All runs use the same T so the fused scan (whose trip count is baked
-    into the jit) hits its compile cache on the timed runs; best-of-repeats
-    suppresses scheduler noise on small containers.
-    """
-    base = dict(
-        num_clients=K, scenario="clean", rounds=rounds, local_epochs=1,
-        batch_size=BATCH, hidden=HIDDEN, dropout=False, seed=0, engine=engine,
-    )
-    cfg = ServerConfig(rule="afa", num_clients=K)
-    fed_run(None, SimConfig(**base), cfg, data=data)  # warmup/compile
-    best = float("inf")
-    for _ in range(REPEATS):
-        res = fed_run(None, SimConfig(**base), cfg, data=data)
-        ts = sorted(res.round_times)
-        best = min(best, ts[len(ts) // 2])
-    return best
 
 
 # compaction scenario geometry: 40% byzantine, blocked by AFA within the
@@ -689,6 +659,8 @@ def run_kernel(tiny: bool = False) -> tuple[list[dict], list[dict]]:
 
 def run(quick: bool = False, tiny: bool = False,
         client_scaling_only: bool = False) -> list[dict]:
+    """Every scenario, sized by ``tiny``; ``quick`` (from ``benchmarks.run``)
+    changes nothing here."""
     if client_scaling_only:
         cs_rows, cs_record = run_client_scaling(tiny=tiny)
         with open(OUT_JSON, "w") as f:
@@ -702,37 +674,7 @@ def run(quick: bool = False, tiny: bool = False,
                 "client_scaling": cs_record,
             }, f, indent=2)
         return cs_rows
-    if tiny:
-        ks, rounds = [10], 8
-    elif quick:
-        ks, rounds = [10, 50], 30
-    else:
-        ks, rounds = [10, 50, 200], 30
-    rows, record = [], []
-    for K in ks:
-        data = make_mnist_like(n_train=K * PER_CLIENT, n_test=200, dim=DIM)
-        t_batched = _measure(data, K, "batched", rounds)
-        t_fused = _measure(data, K, "fused", rounds)
-        speedup = t_batched / max(t_fused, 1e-9)
-        for name, t in [("batched", t_batched), ("fused", t_fused)]:
-            rows.append({
-                "name": f"fused_engine/K{K}/{name}",
-                "us_per_call": round(t * 1e6, 1),
-                "derived": "",
-            })
-        rows.append({
-            "name": f"fused_engine/K{K}/speedup",
-            "us_per_call": "",
-            "derived": f"fused={speedup:.1f}x_vs_batched",
-        })
-        record.append({
-            "K": K,
-            "batched_round_s": round(t_batched, 6),
-            "fused_round_s": round(t_fused, 6),
-            "speedup": round(speedup, 2),
-        })
-    compact_rows, compact_record = run_compaction(tiny=tiny)
-    rows.extend(compact_rows)
+    rows, compact_record = run_compaction(tiny=tiny)
     packed_rows, packed_record = run_packed(tiny=tiny)
     rows.extend(packed_rows)
     kernel_rows, kernel_record = run_kernel(tiny=tiny)
@@ -745,10 +687,8 @@ def run(quick: bool = False, tiny: bool = False,
         json.dump({
             "workload": {
                 "dim": DIM, "hidden": list(HIDDEN), "batch": BATCH,
-                "per_client": PER_CLIENT, "scenario": "clean", "rule": "afa",
-                "rounds_timed": rounds, "repeats": REPEATS,
+                "per_client": PER_CLIENT, "repeats": REPEATS,
             },
-            "results": record,
             "compaction": compact_record,
             "packed": packed_record,
             "kernel": kernel_record,
@@ -762,9 +702,8 @@ if __name__ == "__main__":
     from benchmarks.common import emit
 
     ap = argparse.ArgumentParser()
-    ap.add_argument("--quick", action="store_true", help="K in {10, 50} only")
     ap.add_argument("--tiny", action="store_true",
-                    help="seconds-scale CI smoke: K=10, T=8")
+                    help="seconds-scale CI smoke")
     ap.add_argument("--client-scaling", action="store_true",
                     help="run ONLY the client-sharded scaling scenario")
     ap.add_argument("--client-scaling-worker", action="store_true",
@@ -774,5 +713,4 @@ if __name__ == "__main__":
         cs_rows, cs_record = _client_scaling_core(tiny=args.tiny)
         print(_CS_MARK + json.dumps({"rows": cs_rows, "record": cs_record}))
     else:
-        emit(run(quick=args.quick, tiny=args.tiny,
-                 client_scaling_only=args.client_scaling))
+        emit(run(tiny=args.tiny, client_scaling_only=args.client_scaling))

@@ -14,7 +14,7 @@ from benchmarks.common import emit
 from repro.utils.compile_cache import use_compile_cache
 
 MODULES = ["table1_robustness", "table2_detection", "fig2_convergence",
-           "fig3_aggregation_time", "round_engine", "fused_engine",
+           "fig3_aggregation_time", "fused_engine",
            "ablation_xi", "roofline"]
 
 

@@ -7,7 +7,8 @@ from __future__ import annotations
 import numpy as np
 
 from repro.data import make_mnist_like, make_spambase_like
-from repro.fed import ServerConfig, SimConfig, run
+from repro.fed import ServerConfig, SimConfig
+from repro.fed import run as fed_run
 
 SCENARIOS = ["clean", "byzantine", "flipping", "noisy"]
 RULES = ["afa", "fa", "mkrum", "comed"]
@@ -29,11 +30,11 @@ def run(quick: bool = False) -> list[dict]:
                     dropout=False, seed=0,
                     lr=0.1 if dname == "mnist_like" else 0.05,
                 )
-                res = run(None, sim, ServerConfig(rule=rule, num_clients=10), data=data)
+                res = fed_run(None, sim, ServerConfig(rule=rule, num_clients=10), data=data)
                 err = float(np.mean(res.test_error[-3:]))
                 rows.append({
                     "name": f"table1/{dname}/{scenario}/{rule}",
-                    "us_per_call": round(res.agg_time * 1e6, 1),
+                    "us_per_call": round(res.round_time * 1e6, 1),
                     "derived": f"test_err={err:.2f}%",
                 })
     return rows

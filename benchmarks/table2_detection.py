@@ -4,7 +4,8 @@ number of rounds needed to block them, per scenario."""
 from __future__ import annotations
 
 from repro.data import make_mnist_like, make_spambase_like
-from repro.fed import ServerConfig, SimConfig, run
+from repro.fed import ServerConfig, SimConfig
+from repro.fed import run as fed_run
 
 SCENARIOS = ["byzantine", "flipping", "noisy"]
 
@@ -22,7 +23,7 @@ def run(quick: bool = False) -> list[dict]:
                 num_clients=10, scenario=scenario, rounds=rounds, local_epochs=2,
                 batch_size=200, hidden=hidden, dropout=False, seed=0,
             )
-            res = run(None, sim, ServerConfig(rule="afa", num_clients=10), data=data)
+            res = fed_run(None, sim, ServerConfig(rule="afa", num_clients=10), data=data)
             rows.append({
                 "name": f"table2/{dname}/{scenario}",
                 "us_per_call": "",

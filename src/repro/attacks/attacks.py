@@ -120,7 +120,7 @@ def byzantine_update_tree(
     segmented fused engine can compact blocked clients out of the stack and
     still draw bit-identical noise for the survivors (``client_ids`` carries
     the original ids through the compaction's index map; ``None`` means the
-    identity layout ``0..K-1``, the host engines' case)."""
+    identity layout ``0..K-1``, the uncompacted case)."""
     leaves, treedef = jax.tree_util.tree_flatten(proposals)
     prev = jax.tree_util.tree_leaves(w_prev)
     K = leaves[0].shape[0]

@@ -1,11 +1,8 @@
 """repro.fed.api — the ONE front door for running federated experiments.
 
-Historically the repo grew three entrypoints that callers had to pick between
-by hand: ``run_simulation`` (the classification simulator over its four round
-engines), ``run_sweep`` (the seed-vmapped fused sweep), and
-``run_llm_simulation`` (the LLM/LoRA fused driver in ``fed/workload.py``).
-:func:`run` routes between them from its arguments, so examples, benchmarks,
-and CI all call one function:
+:func:`run` routes from its arguments to the classification simulator, the
+seed-vmapped sweep, or the LLM/LoRA driver, so examples, benchmarks and CI
+all call one function:
 
     from repro.fed.api import run
 
@@ -30,10 +27,6 @@ Routing rules:
   :class:`~repro.fed.engine.FusedData` or a corpus of token sequences, and
   a ``server`` given sets the rule's options); extra keyword args
   (``local_steps``, ``samples_per_client``, ``seq``, ...) pass through.
-
-The old names still work as thin shims that emit ``DeprecationWarning`` and
-delegate to the same implementations (``tests/test_api.py`` asserts the
-facade's trajectories are bit-identical to the shims').
 """
 
 from __future__ import annotations

@@ -1,37 +1,31 @@
 """Device-resident round engines: vmapped client training over a stacked
-client axis, and the fused T-round ``lax.scan`` simulation (DESIGN.md §2).
+client axis, inside the fused T-round ``lax.scan`` simulation (DESIGN.md §2).
 
-The looped simulator path dispatches one jit per client per round and
-round-trips every proposal through host numpy.  The **batched** engine
-replaces that with ONE jit call per round that:
+One round is:
 
   1. **client layer** — vmaps ``local_sgd`` over stacked shards
      (leaves ``(K, S, b, ...)``) and per-client RNG keys, training all K
      clients in a single device program;
   2. **selection by mask** — clients that do not train this round
-     (update-level attackers, blocked clients) are row-selected back to
-     ``w_t``, no Python branching over clients;
+     (update-level attackers, blocked clients) hold ``w_t``, no Python
+     branching over clients;
   3. **proposal layer** — the update-level attacks (byzantine / alie / ipm)
      run as jit-able transforms on the stacked proposal pytree
      (``repro.attacks.apply_update_attack``), so proposals never leave the
-     device.
+     device;
+  4. **server layer** — the pure ``server_step`` (screening, aggregation,
+     reputation and blocking).
 
-Aggregation then goes through the registry tree dispatch
-(``FedServer.aggregate_tree`` -> ``repro.core.dispatch_rule_tree``): AFA
-consumes the stacked pytree natively; matrix-form rules flatten *inside jit*
-(pure jnp reshapes).  The per-round host work is reduced to drawing minibatch
-indices and the K-scalar reputation update.
-
-The **fused** engine (``make_fused_sim``) removes even that: the entire
-T-round simulation is ONE jit — ``lax.scan`` over rounds with ``(params,
+The **fused** engine (``make_fused_sim``) runs the entire T-round
+simulation as ONE jit — ``lax.scan`` over rounds with ``(params,
 ServerState)`` as carry, minibatch indices drawn *on device* with
-``jax.random`` from padded ``(K, n_max, ...)`` shard stacks, the pure
-``server_step`` (reputation + blocking) inlined into the scan body, and the
-per-round test error emitted as a scan output.  Host↔device syncs drop from
-O(T) to O(1), and a whole simulation becomes a vmappable value — ``run_sweep``
-maps it over a seed axis in a single device program.  Its local update runs
-over the rows that can train (``train_rows``, :func:`trainer_count`), not
-over the byzantine rows whose result the attack overwrites.
+``jax.random`` from padded ``(K, n_max, ...)`` shard stacks, and the
+per-round test error emitted as a scan output.  Host↔device syncs are O(1)
+per simulation, and a whole simulation is a vmappable value —
+``simulator.sweep`` maps it over a seed axis in a single device program.
+Its local update runs over the rows that can train (``train_rows``,
+:func:`trainer_count`), not over the byzantine rows whose result the attack
+overwrites.
 
 The **segmented** form (``make_fused_segment``) is the same scan cut into
 segments of S rounds so the host can *compact* blocked clients out of the
@@ -44,7 +38,7 @@ bit-identical to the uncompacted one while paying FLOPs only for ~K_live
 rows.  This is AFA's headline efficiency claim (blocking *reduces*
 computation) made true in the implementation.
 
-RNG stream separation (shared by all four engines): per-client keys are
+RNG stream separation (shared by every engine): per-client keys are
 ``fold_in(fold_in(PRNGKey(seed), CLIENT_STREAM), round * K + client_id)``
 with K the FULL client count — injective over (round, client), so keys never
 collide across rounds (the old ``PRNGKey(round * 1000 + k)`` collided as soon
@@ -88,7 +82,7 @@ SHARDABLE_SCENARIOS = ROW_LOCAL_SCENARIOS + ("alie", "ipm")
 
 
 class EngineConfig(NamedTuple):
-    """Static (trace-time) knobs of the batched round step."""
+    """Static (trace-time) knobs of the round's client and attack layers."""
 
     scenario: str = "clean"      # clean | byzantine | flipping | noisy | alie | ipm
     lr: float = 0.1
@@ -124,8 +118,7 @@ def client_keys_traced(seed, rnd, client_ids, num_clients: int) -> jnp.ndarray:
 
 
 def client_keys(seed: int, rnd: int, num_clients: int) -> jnp.ndarray:
-    """Host-eager form of :func:`client_keys_traced` over all K clients —
-    the per-round key stack of the looped and batched engines."""
+    """Host-eager form of :func:`client_keys_traced` over all K clients."""
     return client_keys_traced(
         seed, rnd, jnp.arange(num_clients, dtype=jnp.uint32), num_clients
     )
@@ -143,8 +136,8 @@ def _train_and_attack(
     """The shared proposal pipeline: vmapped local training over the stacked
     client axis, non-trainer rows reset to the current proposal-space point
     ``w_t``, update-level attacks applied by mask.  ONE implementation traced
-    by both the batched per-round step and the fused scan body, so the
-    engines cannot drift apart.  ``client_ids`` maps rows to original client
+    by the fused scan body, the serve tier and the client-sharded round, so
+    they cannot drift apart.  ``client_ids`` maps rows to original client
     ids under compaction (None = identity layout); ``client_axis`` names the
     mesh axis when the stack is client-sharded (alie/ipm psum their benign
     moments over it).  ``rows`` is the trainer list of
@@ -192,28 +185,6 @@ def _train_and_attack(
             client_ids=client_ids,
             axis_name=client_axis,
         ), stats
-
-
-@functools.lru_cache(maxsize=64)
-def make_train_attack_step(workload, cfg: EngineConfig):
-    """Build the jit'd proposal producer.
-
-    Returns ``step(params, batch, keys, train_mask, bad_mask, benign_mask,
-    akey) -> stacked proposals``, where ``batch`` leaves are
-    ``(K, S, b, ...)``, masks are ``(K,)`` bool, and the result is a
-    proposal-space pytree with a leading client axis on every leaf.  Cached
-    on (workload, cfg) — workloads are frozen dataclasses, so reconstructing
-    an equal workload reuses the compiled step.
-    """
-
-    @jax.jit
-    def step(params, batch, keys, train_mask, bad_mask, benign_mask, akey):
-        return _train_and_attack(
-            workload, cfg, params, batch, keys, train_mask, bad_mask,
-            benign_mask, akey,
-        )[0]
-
-    return step
 
 
 # ---------------------------------------------------------------------------
@@ -490,7 +461,7 @@ def make_fused_sim(
       jit: ``lax.scan`` of the round body over ``T = num_rounds`` rounds,
       carry ``(params, ServerState)``, with minibatch indices drawn on device
       and the per-round (test error, good_mask, blocked) trajectory emitted
-      as scan outputs.  ``seed`` may be traced — ``run_sweep`` vmaps it.
+      as scan outputs.  ``seed`` may be traced — ``simulator.sweep`` vmaps it.
       With ``keep_round1`` the carry also holds the params after round 1 and
       ``scan_fn`` returns them last, ``(params_T, state_T, traj, params_1)``:
       one round of the timed program that a reference can replay.

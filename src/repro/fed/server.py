@@ -9,15 +9,16 @@ shell (DESIGN.md §2/§3):
   ``rounds_blocked`` bookkeeping, and the round counter.
 * ``server_step(state, proposals, n_k, mask0, ...) -> (state', result)`` —
   ONE pure implementation of "aggregate + absorb the screening outcome".
-  Runs eagerly (host engines) or traced inside the fused ``lax.scan``
-  (``SimConfig.engine="fused"``), so both paths share one source of truth.
-* ``FedServer`` — the stateful wrapper the host engines drive; it owns a
-  ``ServerState`` and replaces it with ``server_step``'s output each round.
+  Traced inside the fused ``lax.scan`` (``SimConfig.engine="fused"``) and
+  run eagerly by ``FedServer``, so both share one source of truth (the
+  serve tier runs its versioned form, ``server_step_versioned``).
+* ``FedServer`` — a stateful wrapper that drives ``server_step`` eagerly,
+  one call at a time; it owns a ``ServerState`` and replaces it with
+  ``server_step``'s output each round.
 
-Proposals arrive either as a dense ``(K, d)`` matrix (``aggregate``, the
-paper-scale looped path) or as a stacked pytree with a leading client axis
-(``aggregate_tree``, the device-resident round engines).  Both routes go
-through the single ``dispatch_rule`` / ``dispatch_rule_tree`` interface in
+Proposals arrive either as a dense ``(K, d)`` matrix (``aggregate``) or as a
+stacked pytree with a leading client axis (``aggregate_tree``).  Both routes
+go through the single ``dispatch_rule`` / ``dispatch_rule_tree`` interface in
 ``repro.core``; AFA is the paper's rule, the others are comparison baselines.
 
 Proposals live in the **workload's proposal space** (DESIGN.md §Workload
@@ -410,7 +411,7 @@ def server_step_versioned(
 
 
 # ---------------------------------------------------------------------------
-# stateful shell — host engines drive this
+# stateful shell — drives server_step eagerly, one round per call
 # ---------------------------------------------------------------------------
 
 

@@ -2,47 +2,33 @@
 dataset, with clean / byzantine / flipping / noisy / alie / ipm scenarios —
 reproduces the paper's Tables 1-2 and the convergence figures.
 
-Four round engines (DESIGN.md §2), selected by ``SimConfig.engine``:
+Two round engines (DESIGN.md §2), selected by ``SimConfig.engine``:
 
-  * ``batched`` (default) — the device-resident round: one jit call per round
-    vmaps ``local_sgd`` over a stacked client axis, applies the update-level
-    attacks as stacked-pytree transforms on device, and aggregates through
-    the registry tree dispatch.  Proposals never round-trip through host
-    numpy, but the loop over rounds (and the minibatch draws) stay on host.
-  * ``looped`` — the reference path: one jit dispatch per client per round.
-    Aggregation goes through the same registry tree dispatch, so the engines
-    differ only in the client layer.  Kept for equivalence testing and as the
-    baseline of ``benchmarks/round_engine.py``.
-  * ``fused`` — the whole T-round simulation as ONE jit: ``lax.scan`` over
-    rounds with ``(params, ServerState)`` as carry, minibatch indices drawn
-    on device with ``jax.random`` from padded ``(K, n_max, ...)`` shard
-    stacks, and the per-round trajectory emitted as scan outputs.  O(1)
-    host↔device syncs per simulation instead of O(T); ``run_sweep`` vmaps it
-    over a seed axis.  With ``SimConfig.segment_rounds > 0`` the scan is cut
-    into S-round segments and (``compact=True``) blocked clients are
-    compacted out of the stacked layout between segments — power-of-two
-    buckets, original-id-keyed RNG streams — producing a bit-identical
-    trajectory while paying FLOPs only for live clients (DESIGN.md §2).
+  * ``fused`` (default) — the whole T-round simulation as ONE jit:
+    ``lax.scan`` over rounds with ``(params, ServerState)`` as carry,
+    minibatch indices drawn on device with ``jax.random`` from padded
+    ``(K, n_max, ...)`` shard stacks, and the per-round trajectory emitted
+    as scan outputs.  O(1) host↔device syncs per simulation instead of
+    O(T); :func:`sweep` vmaps it over a seed axis.  With
+    ``SimConfig.segment_rounds > 0`` the scan is cut into S-round segments
+    and (``compact=True``) blocked clients are compacted out of the stacked
+    layout between segments — power-of-two buckets, original-id-keyed RNG
+    streams — producing a bit-identical trajectory while paying FLOPs only
+    for live clients (DESIGN.md §2).
   * ``fused_eager`` — the fused round body run eagerly one round at a time:
     the bit-equivalence reference for the fused scan
     (``tests/test_fused_engine.py``).
 
 Aggregation representation (``ServerConfig.agg_layout``, DESIGN.md §3): by
-default every engine packs the stacked proposal pytree into one contiguous
-``(K, D)`` buffer per round and runs the rules' matrix forms on it
-("packed"); "tree" packs inside the dispatch instead (bit-identical), and
-"leaf" keeps the legacy per-leaf path as the benchmark reference.
+default the round packs the stacked proposal pytree into one contiguous
+``(K, D)`` buffer and runs the rules' matrix forms on it ("packed"); "tree"
+packs inside the dispatch instead (bit-identical), and "leaf" keeps the
+legacy per-leaf path as the benchmark reference.
 
-All four engines key per-client RNG as ``fold_in(fold_in(PRNGKey(seed),
-CLIENT_STREAM), round * K + k)`` and the attack noise as
-``fold_in(PRNGKey(seed), round)``.  ``batched`` and ``looped`` additionally
-draw minibatch indices from the same host numpy stream, so on fixed seeds
-they produce matching per-round trajectories (test error, ``good_mask``
-history); see ``tests/test_round_engine.py``.  The fused engines draw
-minibatch indices from a ``jax.random`` stream instead (there is no host RNG
-inside a scan), so fused trajectories are equivalent in distribution — not
-bitwise — to the host engines'; the batched engine stays the reference
-implementation of the round itself.
+Both engines key per-client RNG as ``fold_in(fold_in(PRNGKey(seed),
+CLIENT_STREAM), round * K + k)``, the attack noise as ``fold_in(PRNGKey(seed),
+round)``, and the minibatch draws under their own device stream, so on
+fixed seeds they produce the same trajectory bit for bit.
 
 Byzantine clients skip training entirely and send w_t + N(0, 20^2 I) (the
 paper's update-level fault); flipping/noisy clients poison their *shard* and
@@ -54,7 +40,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-import warnings
 import weakref
 from typing import NamedTuple
 
@@ -62,12 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.attacks import (
-    UPDATE_ATTACK_SCENARIOS,
-    apply_update_attack,
-    flip_labels,
-    noisy_features,
-)
+from repro.attacks import flip_labels, noisy_features
 from repro.data import (
     SyntheticClassification,
     dirichlet_shard_indices,
@@ -79,18 +59,13 @@ from repro.data.sharding import stack_dtype
 from repro.fed.engine import (
     EngineConfig,
     FusedData,
-    FusedTrajectory,
-    attack_key,
-    client_keys,
     make_fused_segment,
     make_fused_sim,
-    make_train_attack_step,
     place_on_client_mesh,
     sweep_fused_sim,
     trainer_count,
 )
 from repro.fed.server import (
-    FedServer,
     ServerConfig,
     gather_server_state,
     init_server_state,
@@ -100,7 +75,6 @@ from repro.fed.server import (
 )
 from repro.fed.workload import DnnWorkload
 from repro.utils.spans import span
-from repro.utils.trees import tree_stack
 
 
 @dataclasses.dataclass
@@ -119,13 +93,13 @@ class SimConfig:
     hidden: tuple = (512, 256)
     sharding: str = "iid"        # iid | dirichlet (non-IID label skew)
     dirichlet_alpha: float = 0.5
-    engine: str = "batched"      # batched | looped | fused | fused_eager
-    # fused engine only: > 0 cuts the one-shot scan into segments of this
+    engine: str = "fused"        # fused | fused_eager
+    # engine="fused" only: > 0 cuts the one-shot scan into segments of this
     # many rounds, with host-side compaction of blocked clients between
     # segments when ``compact`` is set (0 = single scan, no compaction)
     segment_rounds: int = 0
     compact: bool = True
-    # fused engine only: > 0 runs the scan client-sharded under shard_map
+    # engine="fused" only: > 0 runs the scan client-sharded under shard_map
     # over a ``client`` mesh axis of this many devices (DESIGN.md §4) —
     # data stacks, server state, and the packed proposal buffer split
     # K / client_shards rows per device, AFA screens hierarchically, and
@@ -138,20 +112,16 @@ class SimConfig:
 @dataclasses.dataclass
 class SimResult:
     test_error: list            # per round
-    train_time: float           # mean per round: local training (+ attacks)
-    agg_time: float             # mean per round: server aggregation
     blocked_round: np.ndarray   # (K,) round at which blocked (-1 = never)
     bad_clients: np.ndarray     # indices
     good_mask_history: list
     detection_rate: float       # fraction of bad clients blocked by the end
     mean_rounds_to_block: float
-    round_time: float = 0.0     # mean per round: batch draw + train +
-                                # aggregate + eval dispatch (host engines eval
-                                # in-loop, symmetric with the fused scan)
+    round_time: float = 0.0     # mean per round, wall clock of the run
     round_times: list = dataclasses.field(default_factory=list)  # raw per-round
     params: object = None       # global parameters after the last round
-    # fused engines: AFA's final-iteration cosine similarities, one (K,)
-    # row per round (zeros for rules without them; empty elsewhere)
+    # AFA's final-iteration cosine similarities, one (K,) row per round
+    # (zeros for rules without them)
     similarity_history: list = dataclasses.field(default_factory=list)
 
 
@@ -187,15 +157,15 @@ class _Setup:
                 x, y = noisy_features(x, y, self.rng, binary=binary)
             rewritten |= x is not shard[0] or y is not shard[1]
             self.poisoned.append((x, y))
-        # shards that are rows of ``data`` untouched: the fused engines then
-        # stage them from a device-resident copy of the dataset (_pool)
+        # shards that are rows of ``data`` untouched: the round then stages
+        # them from a device-resident copy of the dataset (_pool)
         self.rows_of_data = not rewritten
 
         out_units = 1 if binary else data.num_classes
         self.sizes = (data.dim, *sim.hidden, out_units)
         # the classification simulator drives the paper-DNN workload by
-        # default (the facade may inject a compatible override); all engines
-        # below consume it only through the ClientWorkload protocol
+        # default (the facade may inject a compatible override); the engines
+        # consume it only through the ClientWorkload protocol
         self.workload = (
             workload if workload is not None else DnnWorkload(self.sizes)
         )
@@ -203,10 +173,9 @@ class _Setup:
         self.n_k = np.asarray([len(x) for x, _ in self.poisoned], np.float32)
         self.x_test = jnp.asarray(data.x_test)
         self.y_test = jnp.asarray(data.y_test.astype(np.int32))
-        self.err_fn = jax.jit(self.workload.eval_metric)
 
-        # uniform per-round minibatch geometry (both engines; stacking needs
-        # one (S, b) for every client).  Keyed to the MEAN shard so skewed
+        # uniform per-round minibatch geometry (stacking needs one (S, b)
+        # for every client).  Keyed to the MEAN shard so skewed
         # (dirichlet) splits don't under-train large clients; sampling is with
         # replacement, so b may exceed a small shard's length.  For equal
         # shards this reduces to the per-client geometry.
@@ -216,20 +185,6 @@ class _Setup:
             int(np.mean(lens)) // sim.batch_size, 1
         )
 
-    def trainers(self, selected) -> list:
-        """Selected clients that actually run local SGD this round, in
-        ascending order (update-level attackers send forged updates instead)."""
-        skip_bad = self.sim.scenario in UPDATE_ATTACK_SCENARIOS
-        return [int(k) for k in selected if not (skip_bad and self.bad_mask[k])]
-
-    def draw_indices(self, trainers: list) -> dict:
-        """Consume the shared numpy stream — identically in both engines."""
-        out = {}
-        for k in trainers:
-            x, _ = self.poisoned[k]
-            out[k] = self.rng.integers(0, len(x), size=(self.batch_s, self.batch_b))
-        return out
-
     def engine_config(self) -> EngineConfig:
         s = self.sim
         return EngineConfig(
@@ -238,14 +193,11 @@ class _Setup:
         )
 
     def result(self, blocked_round: np.ndarray, test_error, good_hist,
-               t_train, t_agg, round_times, params=None,
-               sim_hist=()) -> SimResult:
-        sim, bad = self.sim, self.bad
+               round_times, params=None, sim_hist=()) -> SimResult:
+        bad = self.bad
         rate, mean_rounds = detection_stats(blocked_round, bad)
         return SimResult(
             test_error=test_error,
-            train_time=t_train / sim.rounds,
-            agg_time=t_agg / sim.rounds,
             blocked_round=blocked_round,
             bad_clients=bad,
             good_mask_history=good_hist,
@@ -276,27 +228,6 @@ def detection_stats(blocked_round: np.ndarray, bad: np.ndarray):
     return rate, mean_rounds
 
 
-def run_simulation(
-    data: SyntheticClassification,
-    sim: SimConfig,
-    server_cfg: ServerConfig,
-    *,
-    eval_every: int = 1,
-) -> SimResult:
-    """DEPRECATED — call :func:`repro.fed.api.run` instead.
-
-    Thin shim over :func:`simulate` (bit-identical trajectory), kept so
-    existing callers keep working with a warning.
-    """
-    warnings.warn(
-        "run_simulation is deprecated; use repro.fed.api.run(workload, sim, "
-        "server, data=data) instead",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    return simulate(data, sim, server_cfg, eval_every=eval_every)
-
-
 def simulate(
     data: SyntheticClassification,
     sim: SimConfig,
@@ -307,159 +238,20 @@ def simulate(
 ) -> SimResult:
     """The classification-simulator implementation behind
     ``repro.fed.api.run`` — route ``sim.engine`` to its round engine."""
-    with span("fed.setup") as attrs:
-        setup = _Setup(data, sim, workload=workload)
-        attrs["h2d_bytes"] = int(setup.x_test.nbytes + setup.y_test.nbytes)
+    if sim.engine not in ("fused", "fused_eager"):
+        raise ValueError(f"unknown engine {sim.engine!r} (fused | fused_eager)")
     if sim.client_shards > 0 and sim.engine != "fused":
         raise ValueError(
             f"client_shards requires engine='fused' (got {sim.engine!r})"
         )
-    if sim.engine == "batched":
-        return _run_batched(setup, server_cfg, eval_every)
-    if sim.engine == "looped":
-        return _run_looped(setup, server_cfg, eval_every)
-    if sim.engine == "fused":
-        if sim.segment_rounds > 0:
-            return _run_fused_segmented(setup, server_cfg, eval_every)
-        return _run_fused(setup, server_cfg, eval_every)
+    with span("fed.setup") as attrs:
+        setup = _Setup(data, sim, workload=workload)
+        attrs["h2d_bytes"] = int(setup.x_test.nbytes + setup.y_test.nbytes)
     if sim.engine == "fused_eager":
         return _run_fused(setup, server_cfg, eval_every, eager=True)
-    raise ValueError(
-        f"unknown engine {sim.engine!r} (batched | looped | fused | fused_eager)"
-    )
-
-
-# ---------------------------------------------------------------------------
-# batched engine — device-resident round (DESIGN.md §2)
-# ---------------------------------------------------------------------------
-
-
-def _run_batched(setup: _Setup, server_cfg: ServerConfig, eval_every: int) -> SimResult:
-    sim = setup.sim
-    K = sim.num_clients
-    server = FedServer(server_cfg)
-    params = setup.params0
-    step = make_train_attack_step(setup.workload, setup.engine_config())
-    dim = setup.poisoned[0][0].shape[1]
-    S, b = setup.batch_s, setup.batch_b
-    bad_j = jnp.asarray(setup.bad_mask)
-
-    test_error, good_hist, round_times = [], [], []
-    t_train = t_agg = 0.0
-    for rnd in range(sim.rounds):
-        t_start = time.perf_counter()
-        selected = server.select()
-        trainers = setup.trainers(selected)
-        idx = setup.draw_indices(trainers)
-
-        xb = np.zeros((K, S, b, dim), np.float32)
-        yb = np.zeros((K, S, b), np.int32)
-        for k, ix in idx.items():
-            x, y = setup.poisoned[k]
-            xb[k] = x[ix]
-            yb[k] = y[ix].astype(np.int32)
-        batch = {"x": jnp.asarray(xb), "y": jnp.asarray(yb)}
-        train_mask = np.zeros(K, bool)
-        train_mask[trainers] = True
-        mask0 = server.participation_mask(selected)
-        benign = mask0 & ~setup.bad_mask
-
-        t0 = time.perf_counter()
-        proposals = step(
-            params, batch, client_keys(sim.seed, rnd, K),
-            jnp.asarray(train_mask), bad_j & jnp.asarray(mask0),
-            jnp.asarray(benign), attack_key(sim.seed, rnd),
-        )
-        jax.block_until_ready(proposals)
-        t_train += time.perf_counter() - t0
-
-        t0 = time.perf_counter()
-        agg, info = server.aggregate_tree(proposals, setup.n_k, selected)
-        if not info["all_blocked"]:  # zero update: keep previous params
-            params = agg
-        jax.block_until_ready(params)
-        t_agg += time.perf_counter() - t0
-        good_hist.append(info.get("good_mask"))
-
-        if rnd % eval_every == 0 or rnd == sim.rounds - 1:
-            test_error.append(
-                float(setup.err_fn(params, setup.x_test, setup.y_test)) * 100.0
-            )
-        # includes the eval dispatch, symmetric with the fused scan (which
-        # evaluates every round in-scan) so engine benchmarks compare like
-        # for like at eval_every=1
-        round_times.append(time.perf_counter() - t_start)
-
-    return setup.result(
-        server.rounds_blocked, test_error, good_hist, t_train, t_agg, round_times,
-        params,
-    )
-
-
-# ---------------------------------------------------------------------------
-# looped engine — per-client dispatch reference
-# ---------------------------------------------------------------------------
-
-
-def _run_looped(setup: _Setup, server_cfg: ServerConfig, eval_every: int) -> SimResult:
-    sim = setup.sim
-    K = sim.num_clients
-    server = FedServer(server_cfg)
-    params = setup.params0
-    ec = setup.engine_config()
-    bad_j = jnp.asarray(setup.bad_mask)
-
-    test_error, good_hist, round_times = [], [], []
-    t_train = t_agg = 0.0
-    for rnd in range(sim.rounds):
-        t_start = time.perf_counter()
-        selected = server.select()
-        trainers = setup.trainers(selected)
-        idx = setup.draw_indices(trainers)
-        mask0 = server.participation_mask(selected)
-        benign = mask0 & ~setup.bad_mask
-
-        t0 = time.perf_counter()
-        keys = client_keys(sim.seed, rnd, K)  # shared per-client key scheme
-        per_client = [params] * K  # non-trainers hold w_t (masked out later)
-        for k in trainers:
-            x, y = setup.poisoned[k]
-            batches = {
-                "x": jnp.asarray(x[idx[k]]),
-                "y": jnp.asarray(y[idx[k]].astype(np.int32)),
-            }
-            per_client[k] = setup.workload.local_update(
-                ec, params, batches, keys[k]
-            )
-        stacked = tree_stack(per_client)
-        stacked = apply_update_attack(
-            sim.scenario, stacked, params, bad_j & jnp.asarray(mask0),
-            jnp.asarray(benign), attack_key(sim.seed, rnd),
-            byzantine_scale=ec.byzantine_scale, z_max=ec.alie_z_max, eps=ec.ipm_eps,
-        )
-        jax.block_until_ready(stacked)
-        t_train += time.perf_counter() - t0
-
-        # same registry tree dispatch as the batched engine, so the two
-        # engines differ only in the client layer (per-client jit vs vmap)
-        t0 = time.perf_counter()
-        agg, info = server.aggregate_tree(stacked, setup.n_k, selected)
-        if not info["all_blocked"]:  # zero update: keep previous params
-            params = agg
-        jax.block_until_ready(params)
-        t_agg += time.perf_counter() - t0
-        good_hist.append(info.get("good_mask"))
-
-        if rnd % eval_every == 0 or rnd == sim.rounds - 1:
-            test_error.append(
-                float(setup.err_fn(params, setup.x_test, setup.y_test)) * 100.0
-            )
-        round_times.append(time.perf_counter() - t_start)
-
-    return setup.result(
-        server.rounds_blocked, test_error, good_hist, t_train, t_agg, round_times,
-        params,
-    )
+    if sim.segment_rounds > 0:
+        return _run_fused_segmented(setup, server_cfg, eval_every)
+    return _run_fused(setup, server_cfg, eval_every)
 
 
 # ---------------------------------------------------------------------------
@@ -658,8 +450,6 @@ def _run_fused(
 ) -> SimResult:
     sim = setup.sim
     mesh = _client_mesh(sim)
-    if eager and mesh is not None:
-        raise ValueError("fused_eager has no client-sharded form; use engine='fused'")
     data = _fused_data(setup, mesh)
     scan_fn, round_fn = _make_setup_sim(setup, server_cfg, mesh)
 
@@ -690,11 +480,10 @@ def _run_fused(
     ]
     good_hist = [gm for gm in np.asarray(traj.good_mask)]
     per_round = total / max(sim.rounds, 1)
-    # one device program covers all T rounds: per-phase host timings do not
-    # exist, so only round_time is populated (uniformly spread)
+    # one device program covers all T rounds: its time is spread uniformly
     return setup.result(
         np.asarray(state.rounds_blocked), test_error, good_hist,
-        0.0, 0.0, [per_round] * sim.rounds,
+        [per_round] * sim.rounds,
         params, list(np.asarray(traj.similarities)),
     )
 
@@ -968,7 +757,7 @@ def _run_fused_segmented(
         good_hist = [gm for gm in good]
         return setup.result(
             np.asarray(state_full.rounds_blocked), test_error_list, good_hist,
-            0.0, 0.0, list(round_times), params, list(sims),
+            list(round_times), params, list(sims),
         )
 
 
@@ -983,26 +772,6 @@ class SweepResult:
     bad_clients: np.ndarray          # (n_bad,) indices (fixed across seeds)
     detection_rate: np.ndarray       # (n,)
     mean_rounds_to_block: np.ndarray # (n,)
-
-
-def run_sweep(
-    data: SyntheticClassification,
-    sim: SimConfig,
-    server_cfg: ServerConfig,
-    seeds,
-) -> SweepResult:
-    """DEPRECATED — call :func:`repro.fed.api.run` with ``seeds=`` instead.
-
-    Thin shim over :func:`sweep` (bit-identical trajectories), kept so
-    existing callers keep working with a warning.
-    """
-    warnings.warn(
-        "run_sweep is deprecated; use repro.fed.api.run(workload, sim, "
-        "server, data=data, seeds=seeds) instead",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    return sweep(data, sim, server_cfg, seeds)
 
 
 def sweep(
@@ -1028,11 +797,11 @@ def sweep(
     setup = _Setup(data, sim)
     if sim.client_shards > 0:
         raise ValueError(
-            "run_sweep is not wired for the client-sharded engine; "
+            "sweep is not wired for the client-sharded engine; "
             "set client_shards=0 for sweeps"
         )
     if sim.segment_rounds > 0:
-        return _run_sweep_segmented(setup, server_cfg, seeds)
+        return _sweep_segmented(setup, server_cfg, seeds)
     fdata = _fused_data(setup)
     scan_fn, _ = _make_setup_sim(setup, server_cfg)
     _, state, traj = sweep_fused_sim(scan_fn, setup.workload, seeds, fdata)
@@ -1056,7 +825,7 @@ def _sweep_result(setup, seeds, blocked_round, test_error, good_mask):
     )
 
 
-def _run_sweep_segmented(
+def _sweep_segmented(
     setup: _Setup, server_cfg: ServerConfig, seeds
 ) -> SweepResult:
     """Segmented + compacted seed sweep: the per-segment scan is vmapped over

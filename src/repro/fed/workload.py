@@ -1,6 +1,6 @@
 """ClientWorkload — the pluggable client-training layer (DESIGN.md §Workload).
 
-Every round engine (looped, batched, fused, segmented, sharded) runs the same
+Every round engine (fused, segmented, sharded, and the serve tier) runs the same
 pipeline: *propose* (local training per client), *attack* (update-level
 transforms on the stacked proposals), *screen + aggregate* (the AFA stack),
 *apply* (fold the aggregate back into the model).  The engines used to
@@ -43,7 +43,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import warnings
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -525,24 +524,6 @@ def llm_data_from_sequences(seqs, *, clients: int, samples_per_client: int,
         lengths=lengths, n_k=lengths.astype(np.float32),
         x_test=test[:, :-1].astype(np.int32), y_test=test[:, 1:].astype(np.int32),
     )
-
-
-def run_llm_simulation(
-    workload: TransformerLoraWorkload,
-    **kwargs,
-):
-    """DEPRECATED — call :func:`repro.fed.api.run` instead.
-
-    Thin shim over :func:`simulate_llm`, kept so existing callers keep
-    working; ``repro.fed.api.run(workload, sim)`` is the one front door.
-    """
-    warnings.warn(
-        "run_llm_simulation is deprecated; use repro.fed.api.run(workload, "
-        "sim_config) instead",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    return simulate_llm(workload, **kwargs)
 
 
 def simulate_llm(

@@ -65,12 +65,6 @@ def tree_axpy(s, x, y):
     )
 
 
-def tree_stack(trees):
-    """List of identically-structured trees -> one tree with a new leading
-    client axis on every leaf (host-side helper for the looped engine)."""
-    return jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *trees)
-
-
 def tree_broadcast_clients(tree, num_clients: int):
     """Broadcast a single tree to a stacked tree with K identical rows."""
     return jax.tree_util.tree_map(

@@ -1,26 +1,15 @@
 """Facade and kernel-plan tests.
 
-``repro.fed.api.run`` must route bit-identically to the three historical
-entrypoints (which now live on as DeprecationWarning shims), and the four
-kernel/layout knobs must resolve through ONE frozen ``KernelPlan`` with a
-documented precedence and loud conflicts.
+``repro.fed.api.run`` runs the fused scan by default and checks its
+arguments, and the four kernel/layout knobs must resolve through ONE frozen
+``KernelPlan`` with a documented precedence and loud conflicts.
 """
-
-import functools
 
 import numpy as np
 import pytest
 
 from repro.data import make_mnist_like
-from repro.fed import (
-    ServerConfig,
-    SimConfig,
-    run,
-    run_simulation,
-    run_sweep,
-    simulate,
-    sweep,
-)
+from repro.fed import ServerConfig, SimConfig, run, simulate
 from repro.fed.server import resolve_server_plan
 from repro.kernels.policy import KernelPlan, resolve_kernel_plan
 
@@ -45,57 +34,29 @@ def server():
 
 
 # ---------------------------------------------------------------------------
-# 1. the facade routes bit-identically to the deprecated shims
+# 1. the facade: the fused scan by default, loud argument errors
 # ---------------------------------------------------------------------------
 
 
-def test_run_simulation_shim_warns_and_matches_facade(data, sim, server):
-    with pytest.deprecated_call():
-        old = run_simulation(data, sim, server)
-    new = run(None, sim, server, data=data)
-    assert old.test_error == new.test_error  # float-exact trajectories
-    assert np.array_equal(old.blocked_round, new.blocked_round)
-    for a, b in zip(old.good_mask_history, new.good_mask_history):
-        assert np.array_equal(a, b)
+def test_default_engine_is_the_fused_scan(data, server):
+    """``SimConfig()`` selects the fused scan, and ``api.run`` on it runs
+    that scan: the ``fed.run`` span says so and the trajectory is the one
+    ``engine="fused"`` gives, bit for bit."""
+    from repro.utils import spans
 
-
-def test_run_sweep_shim_warns_and_matches_facade(data, sim, server):
-    with pytest.deprecated_call():
-        old = run_sweep(data, sim, server, seeds=[0, 1])
-    new = run(None, sim, server, data=data, seeds=[0, 1])
-    assert np.array_equal(old.seeds, new.seeds)
-    assert np.array_equal(old.test_error, new.test_error)
-    assert np.array_equal(old.blocked_round, new.blocked_round)
-    assert np.array_equal(old.good_mask_history, new.good_mask_history)
-
-
-def test_run_llm_shim_warns_and_matches_facade():
-    from repro.fed import run_llm_simulation
-    from repro.models import ModelConfig
-    from repro.fed.workload import get_workload
-
-    cfg = ModelConfig(
-        name="t-api-lora", family="dense", num_layers=2, d_model=32,
-        vocab_size=64, num_heads=4, num_kv_heads=2, d_ff=64,
-        block_q=16, block_k=16,
-    )
-    workload = get_workload("lora", model_cfg=cfg, rank=2)
-    with pytest.deprecated_call():
-        old = run_llm_simulation(
-            workload, clients=4, byzantine=1, rounds=3, local_steps=1,
-            batch=2, samples_per_client=8, seq=16, n_test=8, seed=0,
-            scenario="byzantine",
-        )
-    sim = SimConfig(
-        num_clients=4, bad_frac=0.25, scenario="byzantine", rounds=3,
-        local_epochs=1, batch_size=2, seed=0, lr=0.2,
-    )
-    new = run(
-        workload, sim, samples_per_client=8, seq=16, n_test=8
-    )
-    assert np.array_equal(old["test_error"], new["test_error"])
-    assert np.array_equal(old["blocked"], new["blocked"])
-    assert np.array_equal(old["good_frac"], new["good_frac"])
+    assert SimConfig().engine == "fused"
+    kw = dict(num_clients=6, bad_frac=0.34, scenario="byzantine", rounds=3,
+              local_epochs=1, batch_size=50, hidden=(8,), seed=0)
+    n0 = max((r.span_id for r in spans.records()), default=0)
+    res = run(None, SimConfig(**kw), server, data=data)
+    (root,) = [r for r in spans.records()
+               if r.span_id > n0 and r.name == "fed.run"]
+    assert root.attrs["engine"] == "fused"
+    ref = run(None, SimConfig(engine="fused", **kw), server, data=data)
+    assert res.test_error == ref.test_error
+    assert np.array_equal(res.blocked_round, ref.blocked_round)
+    assert np.array_equal(np.stack(res.good_mask_history),
+                          np.stack(ref.good_mask_history))
 
 
 def test_facade_argument_errors(data, sim, server):

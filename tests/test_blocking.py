@@ -14,7 +14,7 @@ from repro.fed import (
     SimConfig,
     detection_stats,
     init_server_state,
-    run_simulation,
+    run,
 )
 
 
@@ -72,18 +72,23 @@ def test_detection_stats_edge_cases():
 # -------------- integration: Table 2 minimum rounds to block -----------------
 
 
-@pytest.mark.parametrize("engine", ["batched", "fused"])
-def test_byzantine_clients_block_in_minimum_rounds(engine):
+@pytest.mark.parametrize(
+    "segment_rounds",
+    [pytest.param(0, id="fused"), pytest.param(4, id="fused_segmented")],
+)
+def test_byzantine_clients_block_in_minimum_rounds(segment_rounds):
     """With w_t + N(0, 20^2 I) updates AFA flags bad clients every round from
     round 1, so each is blocked in exactly the prior's minimum number of
     observations (paper Table 2) — and blocked_round is 1-indexed, so the
-    value IS that count."""
+    value IS that count.  Run one-shot and segmented, compacting the blocked
+    clients out between segments."""
     data = make_mnist_like(n_train=2000, n_test=400, dim=784)
     sim = SimConfig(
         num_clients=10, scenario="byzantine", rounds=8, local_epochs=2,
-        batch_size=100, hidden=(512, 256), dropout=False, seed=3, engine=engine,
+        batch_size=100, hidden=(512, 256), dropout=False, seed=3,
+        segment_rounds=segment_rounds, compact=True,
     )
-    res = run_simulation(data, sim, ServerConfig(rule="afa", num_clients=10))
+    res = run(None, sim, ServerConfig(rule="afa", num_clients=10), data=data)
     n_min = min_rounds_to_block()
     assert res.detection_rate == 1.0
     np.testing.assert_array_equal(

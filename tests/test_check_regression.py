@@ -16,9 +16,9 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmarks"))
 import check_regression as cr  # noqa: E402
 
 
-def _doc(packed=1.0, fused=5.5):
+def _doc(packed=1.0, compaction=5.5):
     return {
-        "results": [{"K": 10, "speedup": fused}],
+        "compaction": [{"K": 10, "post_block_speedup": compaction}],
         "packed": [{"K": 200, "D": 545, "rule": "afa", "agg_speedup": packed}],
     }
 
@@ -36,7 +36,7 @@ def test_matching_speedups_pass(tmp_path):
 
 def test_relative_regression_fails(tmp_path):
     # 5.5x -> 3.0x is far past the 25% tolerance
-    assert _run(tmp_path, _doc(fused=3.0), _doc(fused=5.5)) == 1
+    assert _run(tmp_path, _doc(compaction=3.0), _doc(compaction=5.5)) == 1
 
 
 def test_packed_below_parity_fails_despite_relative_tolerance(tmp_path):
@@ -59,4 +59,4 @@ def test_abs_floor_binds_even_with_wide_tolerance(tmp_path):
 
 
 def test_empty_intersection_fails(tmp_path):
-    assert _run(tmp_path, {"results": []}, _doc()) == 1
+    assert _run(tmp_path, {"compaction": []}, _doc()) == 1

@@ -84,7 +84,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np
 from repro.data import make_spambase_like
-from repro.fed.simulator import SimConfig, run_simulation
+from repro.fed.api import run as fed_run
+from repro.fed.simulator import SimConfig
 from repro.fed.server import ServerConfig
 
 K = 20
@@ -101,7 +102,7 @@ def run(shards, seg=0):
         segment_rounds=seg, compact=seg > 0, client_shards=shards, seed=0,
     )
     cfg = ServerConfig(rule="afa", num_clients=K)
-    return run_simulation(data, sim, cfg)
+    return fed_run(None, sim, cfg, data=data)
 
 
 ref = run(0)                 # today's single-device one-shot fused scan
@@ -223,7 +224,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np
 from repro.data import make_spambase_like
-from repro.fed.simulator import SimConfig, run_simulation
+from repro.fed.api import run as fed_run
+from repro.fed.simulator import SimConfig
 from repro.fed.server import ServerConfig
 
 K = 16
@@ -236,7 +238,8 @@ def run(shards, scenario):
         local_epochs=1, batch_size=16, hidden=(8,), engine="fused",
         client_shards=shards, seed=0,
     )
-    return run_simulation(data, sim, ServerConfig(rule="afa", num_clients=K))
+    return fed_run(None, sim, ServerConfig(rule="afa", num_clients=K),
+                   data=data)
 
 
 for scenario in ("alie", "ipm"):

@@ -100,10 +100,10 @@ def test_server_comed_kernel_route_matches_reference():
 
 def test_ipm_scenario_in_simulator():
     from repro.data import make_mnist_like
-    from repro.fed import SimConfig, run_simulation
+    from repro.fed import SimConfig, run
 
     data = make_mnist_like(n_train=1500, n_test=400, dim=196)
     sim = SimConfig(num_clients=10, scenario="ipm", rounds=5, local_epochs=2,
                     batch_size=100, hidden=(64, 32), dropout=False)
-    res = run_simulation(data, sim, ServerConfig(rule="afa", num_clients=10))
+    res = run(None, sim, ServerConfig(rule="afa", num_clients=10), data=data)
     assert np.isfinite(res.test_error[-1])

@@ -16,7 +16,7 @@ from repro.data import (
     make_spambase_like,
     make_token_stream,
 )
-from repro.fed import SimConfig, ServerConfig, run_simulation
+from repro.fed import FedServer, SimConfig, ServerConfig, run
 from repro.fed.distributed import FedRoundConfig, make_fed_round
 from repro.models import ModelConfig, build_model
 from repro.optim import adamw, cosine_schedule, sgd_momentum
@@ -107,7 +107,7 @@ def _run(data, scenario, rule, rounds=8):
         num_clients=10, scenario=scenario, rounds=rounds, local_epochs=2,
         batch_size=100, hidden=(512, 256), dropout=False, seed=3,
     )
-    return run_simulation(data, sim, ServerConfig(rule=rule, num_clients=10))
+    return run(None, sim, ServerConfig(rule=rule, num_clients=10), data=data)
 
 
 def test_afa_robust_to_byzantine_fa_is_not(small_data):
@@ -131,14 +131,28 @@ def test_afa_robust_to_flipping(small_data):
     assert res.detection_rate == 1.0
 
 
-def test_afa_aggregation_cheaper_than_mkrum_comed(small_data):
-    """Paper Fig 3: AFA server time << MKRUM/COMED (same workload here)."""
+def test_afa_aggregation_cheaper_than_mkrum_comed():
+    """Paper Fig 3: AFA server time << MKRUM/COMED.  Times the server step
+    itself on one (K, D) update matrix at the paper DNN's width (K = 10,
+    D = 535,818), median of three calls after a warm-up (compile) call."""
+    import time
+
+    K, D = 10, 535_818
+    rng = np.random.default_rng(0)
+    base = rng.normal(size=(D,)).astype(np.float32)
+    U = jnp.asarray(base + 0.05 * rng.normal(size=(K, D)).astype(np.float32))
+    n_k = np.ones(K, np.float32)
     t = {}
     for rule in ["afa", "mkrum", "comed"]:
-        r = _run(small_data, "clean", rule, rounds=4)
-        t[rule] = r.agg_time
-    # first-round jit compile dominates equally; compare steady relative order
-    assert t["afa"] < 3.0 * min(t["mkrum"], t["comed"]) + 0.5
+        server = FedServer(ServerConfig(rule=rule, num_clients=K))
+        jax.block_until_ready(server.aggregate(U, n_k, np.arange(K))[0])
+        times = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            jax.block_until_ready(server.aggregate(U, n_k, np.arange(K))[0])
+            times.append(time.perf_counter() - t0)
+        t[rule] = sorted(times)[1]
+    assert t["afa"] < 3.0 * min(t["mkrum"], t["comed"]) + 0.5, t
 
 
 def test_blocked_clients_not_selected(small_data):

@@ -280,7 +280,8 @@ def test_fused_trajectory_packed_bit_identical_to_tree(traj_data):
     round) vs packing inside the dispatch is a pure layout change: identical
     (test_error, good_mask, blocked) trajectories, bit for bit, on a
     byzantine workload where AFA blocks clients mid-run."""
-    from repro.fed import SimConfig, run_simulation
+    from repro.fed import SimConfig
+    from repro.fed import run as fed_run
 
     def run(layout):
         sim = SimConfig(
@@ -288,9 +289,10 @@ def test_fused_trajectory_packed_bit_identical_to_tree(traj_data):
             local_epochs=2, batch_size=64, hidden=(32, 16), dropout=True,
             seed=3, engine="fused",
         )
-        return run_simulation(
-            traj_data, sim,
+        return fed_run(
+            None, sim,
             ServerConfig(rule="afa", num_clients=8, agg_layout=layout),
+            data=traj_data,
         )
 
     pk, tr = run("packed"), run("tree")

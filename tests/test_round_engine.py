@@ -1,4 +1,4 @@
-"""Round-engine tests: looped-vs-batched trajectory equivalence, registry
+"""Round-engine tests: engine selection, the client key scheme, registry
 dispatch coverage (every rule in RULES reachable from ServerConfig.rule, in
 both proposal layouts), and the stacked-pytree attack transforms."""
 
@@ -15,56 +15,23 @@ from repro.attacks import (
 )
 from repro.core import RULES, RuleOptions, dispatch_rule_tree
 from repro.data import make_mnist_like
-from repro.fed import FedServer, ServerConfig, SimConfig, run_simulation
+from repro.fed import FedServer, ServerConfig, SimConfig, run
 
 RNG = np.random.default_rng(7)
 
 
-# ------------------- looped vs batched engine equivalence --------------------
+# ------------------------------ engine selection ------------------------------
 
 
-@pytest.fixture(scope="module")
-def eq_data():
-    return make_mnist_like(n_train=1000, n_test=300, dim=196)
-
-
-def _engine_run(data, scenario, engine, rule="afa", dropout=True):
-    sim = SimConfig(
-        num_clients=8, scenario=scenario, rounds=5, local_epochs=2,
-        batch_size=100, hidden=(64, 32), dropout=dropout, seed=3, engine=engine,
-    )
-    return run_simulation(data, sim, ServerConfig(rule=rule, num_clients=8))
-
-
-@pytest.mark.parametrize("scenario", ["clean", "byzantine"])
-def test_engines_equivalent(eq_data, scenario):
-    """Same seeds -> same per-round test error and good_mask history.  The
-    engines share batch sampling, attack keys, and the registry tree
-    dispatch, so only the client layer (per-client jit vs vmap) differs."""
-    looped = _engine_run(eq_data, scenario, "looped")
-    batched = _engine_run(eq_data, scenario, "batched")
-    np.testing.assert_allclose(
-        looped.test_error, batched.test_error, rtol=0, atol=1e-3
-    )
-    assert len(looped.good_mask_history) == len(batched.good_mask_history)
-    for gl, gb in zip(looped.good_mask_history, batched.good_mask_history):
-        np.testing.assert_array_equal(np.asarray(gl), np.asarray(gb))
-
-
-def test_engines_equivalent_under_update_attacks(eq_data):
-    """alie/ipm forge rows from benign statistics — both engines must compute
-    them from the same masked stacked-tree moments."""
-    for scenario in ["alie", "ipm"]:
-        looped = _engine_run(eq_data, scenario, "looped", dropout=False)
-        batched = _engine_run(eq_data, scenario, "batched", dropout=False)
-        np.testing.assert_allclose(
-            looped.test_error, batched.test_error, rtol=0, atol=1e-3
-        )
-
-
-def test_unknown_engine_rejected(eq_data):
-    with pytest.raises(ValueError, match="unknown engine"):
-        _engine_run(eq_data, "clean", "warp")
+@pytest.mark.parametrize("engine", ["batched", "looped", "warp"])
+def test_unknown_engine_rejected(engine):
+    """Only the fused scan and its eager reference remain: a retired or
+    misspelled engine fails loudly instead of running another one."""
+    data = make_mnist_like(n_train=200, n_test=50, dim=16)
+    sim = SimConfig(num_clients=4, rounds=1, local_epochs=1, batch_size=50,
+                    hidden=(8,), engine=engine)
+    with pytest.raises(ValueError, match="unknown engine .*fused \\| fused_eager"):
+        run(None, sim, ServerConfig(rule="afa", num_clients=4), data=data)
 
 
 # --------------------------- client key scheme -------------------------------

@@ -26,6 +26,7 @@ from repro.attacks import UPDATE_ATTACK_SCENARIOS, apply_update_attack
 from repro.core import RuleOptions
 from repro.core.baselines import RULES, _dispatch_tree_jit, dispatch_rule
 from repro.fed.client import local_sgd
+from repro.fed.api import run
 from repro.fed.dnn import dnn_error, dnn_loss, init_dnn
 from repro.fed.engine import (
     _BATCH_STREAM,
@@ -41,13 +42,13 @@ from repro.fed.server import (
     make_rule_options,
     server_step,
 )
+from repro.fed.simulator import SimConfig
 from repro.fed.workload import (
     ADAPTER_CODEC,
     DnnWorkload,
     TransformerLoraWorkload,
     get_workload,
     init_lora_adapters,
-    run_llm_simulation,
 )
 from repro.utils.trees import (
     pack_spec,
@@ -405,11 +406,10 @@ def test_lora_simulation_blocks_byzantine_on_adapter_buffer():
     """6 clients / 2 byzantine on the tiny transformer: AFA screens the
     attackers out every round and blocks them within the horizon, operating
     on an adapter buffer < 5% of the model's parameters."""
-    res = run_llm_simulation(
-        _tiny_lora_workload(), clients=6, byzantine=2, rounds=8,
-        local_steps=2, batch=2, samples_per_client=8, seq=16, n_test=8,
-        seed=0, scenario="byzantine",
-    )
+    sim = SimConfig(num_clients=6, bad_frac=2 / 6, scenario="byzantine",
+                    rounds=8, local_epochs=2, batch_size=2, seed=0, lr=0.2)
+    res = run(_tiny_lora_workload(), sim, samples_per_client=8, seq=16,
+              n_test=8)
     blocked = res["blocked"][-1]
     assert blocked[:2].all(), f"byzantine clients not blocked: {blocked}"
     assert not blocked[2:].any(), f"benign client blocked: {blocked}"
